@@ -30,6 +30,20 @@ def tenant_sweep_sizes(n_tenants: int) -> List[int]:
     return sizes
 
 
+def whole_tier_mesh(n_tiers: int):
+    """Tenant mesh over ALL of the host's devices, each holding whole
+    tiers.  Raises when ``n_tiers`` does not divide over them: a rig
+    never runs on fewer devices than the host has without being told
+    (pass ``mesh=`` to choose)."""
+    from repro.core.transport import make_tenant_mesh
+    mesh = make_tenant_mesh()
+    d = mesh.shape["tenant"]
+    if n_tiers % d:
+        raise ValueError(f"{n_tiers} tiers do not divide over {d} devices; "
+                         f"pass mesh= to choose the devices")
+    return mesh
+
+
 def timeit(fn: Callable, iters: int, warmup: int = 3) -> float:
     """Mean seconds per call, blocking on fn()'s result.
 
@@ -212,15 +226,10 @@ class SwitchEchoRig:
     def __init__(self, n_tiers: int = 8, n_flows: int = 2,
                  batch: int = 4, ring_entries: int = 32,
                  load_per_conn: int = 1, mesh=None):
-        import math
-
         from repro.core.engine import shard_states
-        from repro.core.transport import make_tenant_mesh
         from repro.core.virtualization import Switch
         if mesh is None:
-            # whole tiers per device: shrink the mesh to divide n_tiers
-            mesh = make_tenant_mesh(
-                n_devices=math.gcd(n_tiers, len(jax.devices())))
+            mesh = whole_tier_mesh(n_tiers)
         self.mesh = mesh
         self.n_tiers = n_tiers
         cfg = FabricConfig(n_flows=n_flows, ring_entries=ring_entries,
@@ -405,14 +414,10 @@ class OpenLoopSwitchRig:
     def __init__(self, n_tiers: int = 8, n_flows: int = 2,
                  batch: int = 4, ring_entries: int = 32, mesh=None,
                  mode=None, tile=None):
-        import math
-
         from repro.core import loadgen
-        from repro.core.transport import make_tenant_mesh
         from repro.core.virtualization import Switch
         if mesh is None:
-            mesh = make_tenant_mesh(
-                n_devices=math.gcd(n_tiers, len(jax.devices())))
+            mesh = whole_tier_mesh(n_tiers)
         self.mesh = mesh
         self.n_tiers = n_tiers
         cfg = FabricConfig(n_flows=n_flows, ring_entries=ring_entries,

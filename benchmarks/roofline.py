@@ -5,14 +5,15 @@ Two row families:
 * ``fig11.switch_fused.{unfused_us,fused_us,speedup}.nN`` — measured
   wall time of one ``Switch.switch_step_stacked`` over an N-tier echo
   rig, jnp composition vs the ``switch_step_fused`` Pallas megakernel.
-  The speedup row is the PR's measured contract (gated by ``ci.sh``
-  with ``CI_FUSED_MIN_SPEEDUP``): fusing the whole per-device step into
-  one kernel must beat the materialized XLA-op chain.
+  The megakernel does not compile for a TPU yet (``switch_step.
+  MOSAIC_REFUSAL``), so these rows exist only from the Pallas
+  interpreter on the CPU, a timing no deployment runs.
 
 * ``fig11.roofline.{switch_step,switch_fused}.*`` — static
   bytes/flops of the compiled step via ``repro.launch.hlo_cost``
-  against the ``repro.config.HW`` roofline (compute- vs memory-bound
-  time, arithmetic intensity, attained fraction of the roofline bound).
+  (arithmetic intensity), plus — only on a device kind with published
+  peaks in ``repro.config.HW_BY_KIND`` — the roofline bound and the
+  attained fraction of it.
   These make the fusion claim quantitative: the fused kernel's win
   must show up as fewer HBM bytes per step, not just lower dispatch
   overhead.
@@ -82,32 +83,34 @@ def _switch_rig(n_tiers: int, n_flows: int = 2, batch: int = 4,
     return sw, sw.stack_states(states), handlers
 
 
-def _roofline_rows(tag: str, fn, stacked, measured_us: float):
-    """hlo_cost rows for one compiled step closure."""
-    from repro.config import HW
+def _roofline_rows(tag: str, fn, stacked, measured_us: float, hw):
+    """hlo_cost rows for one compiled step closure.  The bound and the
+    attained share need the device's published peaks (``hw``): they are
+    emitted only when the step ran on a device kind in
+    ``repro.config.HW_BY_KIND``, never against a CPU time."""
     from repro.launch import hlo_cost
 
     hlo = fn.lower(stacked).compile().as_text()
     cost = hlo_cost.analyze(hlo)
     flops = max(cost["flops"], 1)
     bts = max(cost["bytes"], 1)
-    compute_s = flops / HW.peak_flops_bf16
-    memory_s = bts / HW.hbm_bw
-    bound_us = max(compute_s, memory_s) * 1e6
-    intensity = flops / bts
-    attained = bound_us / measured_us if measured_us > 0 else 0.0
     pre = f"fig11.roofline.{tag}"
-    return [
+    rows = [
         (f"{pre}.flops", float(flops), "HLO flops per switch step"),
         (f"{pre}.bytes", float(bts), "HLO HBM bytes per switch step"),
-        (f"{pre}.intensity", intensity,
-         f"flop/byte (ridge={HW.peak_flops_bf16 / HW.hbm_bw:.0f})"),
+        (f"{pre}.intensity", flops / bts, "flop/byte"),
+    ]
+    if hw is None:
+        return rows
+    compute_s = flops / hw.peak_flops_bf16
+    memory_s = bts / hw.hbm_bw
+    bound_us = max(compute_s, memory_s) * 1e6
+    return rows + [
         (f"{pre}.bound_us", bound_us,
-         f"roofline bound on {HW.name}: "
+         f"roofline bound on {hw.name}: "
          f"{'memory' if memory_s >= compute_s else 'compute'}-bound"),
-        (f"{pre}.attained_frac", attained,
-         "bound_us / measured_us (CPU-host measurement vs "
-         f"{HW.name} model)"),
+        (f"{pre}.attained_frac", bound_us / measured_us,
+         f"bound_us / measured_us on {hw.name}"),
     ]
 
 
@@ -116,7 +119,10 @@ def fabric_rows() -> list:
     import jax
 
     from benchmarks.common import timeit
+    from repro.config import hw_spec
 
+    dev = jax.devices()[0]
+    hw = hw_spec(dev.device_kind) if dev.platform == "tpu" else None
     out = []
     hlo_targets = {}
     for n in TIER_SIZES:
@@ -140,8 +146,8 @@ def fabric_rows() -> list:
     # static roofline terms at the largest rig
     n = TIER_SIZES[-1]
     step_un, step_fu, stacked, un_us, fu_us = hlo_targets[n]
-    out += _roofline_rows("switch_step", step_un, stacked, un_us)
-    out += _roofline_rows("switch_fused", step_fu, stacked, fu_us)
+    out += _roofline_rows("switch_step", step_un, stacked, un_us, hw)
+    out += _roofline_rows("switch_fused", step_fu, stacked, fu_us, hw)
     return out
 
 

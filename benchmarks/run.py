@@ -16,9 +16,11 @@ underscore-prefixed keys.
 
 ``--accel-profile {cpu,gpu,tpu}`` applies the matching
 ``repro.config.ACCEL_PROFILES`` environment (x64 off, platform pin,
-latency-hiding scheduler / async-collective XLA flags) BEFORE any
+GPU latency-hiding scheduler / async-collective XLA flags) BEFORE any
 suite imports jax, so the same bench commands run unmodified on
-GPU/TPU hosts.
+GPU/TPU hosts.  All suites run in this one process (a chip belongs to
+one process), sharing JAX's persistent compilation cache
+(``repro.config.enable_compile_cache``).
 """
 from __future__ import annotations
 
@@ -50,6 +52,8 @@ def main() -> None:
     if args.accel_profile:
         from repro.config import apply_accel_profile
         apply_accel_profile(args.accel_profile)
+    from repro.config import enable_compile_cache
+    enable_compile_cache()
     print("name,us_per_call,derived")
     failed = []
     results = {}

@@ -447,8 +447,10 @@ EOF
 echo "== bench: fused switch step vs jnp composition + roofline =="
 # the megakernel perf contract: one fused Pallas switch step must beat
 # the materialized XLA-op chain (gate below), and the static HLO
-# roofline rows must land in the trajectory.  Gate on the FRESH CSV,
-# same policy as the fig11 leg.
+# roofline rows must land in the trajectory.  The device-bound rows
+# (bound_us / attained_frac) need a device kind with published peaks,
+# so they are not required here.  Gate on the FRESH CSV, same policy
+# as the fig11 leg.
 FUSED_CSV="$(mktemp)"
 timeout "$BENCH_TIMEOUT" python -m benchmarks.run --only roofline \
     --json BENCH_fabric.json | tee "$FUSED_CSV"
@@ -471,8 +473,7 @@ required = [f"fig11.switch_fused.{kind}.n{n}"
             for n in (1, 4)]
 required += [f"fig11.roofline.{tag}.{kind}"
              for tag in ("switch_step", "switch_fused")
-             for kind in ("flops", "bytes", "intensity", "bound_us",
-                          "attained_frac")]
+             for kind in ("flops", "bytes", "intensity")]
 missing = [k for k in required if k not in rows]
 bad = [k for k in required if k in rows
        and (not math.isfinite(rows[k]) or rows[k] <= 0)]

@@ -81,7 +81,7 @@ TRACER_ROOTS = {
     "jax.lax.cond", "lax.cond",
     "jax.lax.switch", "lax.switch",
     "jax.jit", "jit",
-    "shard_map", "jax.experimental.shard_map.shard_map",
+    "shard_map", "jax.shard_map", "transport.shard_map",
 }
 
 

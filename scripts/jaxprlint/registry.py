@@ -292,10 +292,10 @@ def _wire_exchange():
     composes it, with the committed words models attached — FLJ105
     compiles these (still nothing executes) and reconciles the HLO
     all-to-all bytes against ``full/compact_exchange_words``."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.core import transport
+    from repro.core.transport import shard_map
 
     mesh = transport.make_tenant_mesh()
     d = mesh.shape["tenant"]
@@ -321,8 +321,7 @@ def _wire_exchange():
 
     args = (_sds((nb, w)), _sds((nb,), jnp.bool_), _sds((nb,)))
     sm = lambda f, outs: jax.jit(shard_map(    # noqa: E731
-        f, mesh=mesh, in_specs=(P(), P(), P()), out_specs=outs,
-        check_rep=False))
+        f, mesh=mesh, in_specs=(P(), P(), P()), out_specs=outs))
     return {
         "n_dev": d,
         "paths": {

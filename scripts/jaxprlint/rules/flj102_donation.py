@@ -8,7 +8,8 @@ kind of rot a perf contract must catch statically.
 
 The check reconciles two independent views of the SAME lowering:
 
-* the traced jaxpr's top-level ``pjit`` eqns declare which flattened
+* the traced jaxpr's top-level jit eqns (``jit``; ``pjit`` before
+  JAX 0.7) declare which flattened
   inputs are donated (``donated_invars``);
 * the lowering marks each really-aliased input: plain jit entries
   carry ``tf.aliasing_output`` arg attributes in StableHLO; shard_map
@@ -36,13 +37,15 @@ DESCRIPTION = ("every donate_argnums buffer must appear in the lowered "
 _ALIAS_RE = re.compile(r"tf\.aliasing_output")
 _DONOR_RE = re.compile(r"jax\.buffer_donor")
 _PAIR_RE = re.compile(r"(?:may|must)-alias")
+# the jit primitive's name: ``jit`` in current JAX, ``pjit`` before 0.7
+JIT_PRIMITIVES = ("jit", "pjit")
 
 
 def _donated_count(jaxpr):
     n = 0
     j = as_jaxpr(jaxpr)
     for eqn in j.eqns:
-        if eqn.primitive.name == "pjit":
+        if eqn.primitive.name in JIT_PRIMITIVES:
             n += sum(bool(d) for d in eqn.params.get("donated_invars",
                                                      ()))
     return n

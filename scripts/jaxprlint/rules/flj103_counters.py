@@ -277,10 +277,9 @@ def _eval_eqn(eqn, args, recurse):
             return [_join([pb[i] for pb in per_branch])
                     for i in range(n_out)]
         return [AV.top(v.aval) for v in eqn.outvars]
-    if name == "pjit" or name in ("custom_jvp_call", "custom_vjp_call",
-                                  "custom_vjp_call_jaxpr", "remat",
-                                  "checkpoint", "closed_call",
-                                  "core_call", "custom_lin"):
+    if name in ("jit", "pjit", "custom_jvp_call", "custom_vjp_call",
+                "custom_vjp_call_jaxpr", "remat", "checkpoint",
+                "closed_call", "core_call", "custom_lin"):
         sub = eqn.params.get("jaxpr") or eqn.params.get("call_jaxpr")
         if sub is not None and as_jaxpr(sub) is not None:
             try:

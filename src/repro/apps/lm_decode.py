@@ -11,10 +11,10 @@ Two fabric shapes matter:
   the parity tests so telemetry matches the uncongested analytic oracle
   (TTFT = prompt_len + 1, ITL = 1);
 * ``backpressure_fabric_config()`` — ``batch_size=1`` egress, so the
-  NIC drains at most one token per flow per step.  Offered load beyond
-  that capacity queues in the rings: TTFT/ITL tails CLIMB with rate,
-  which is what the fig12 lm_decode latency-vs-load rows (and their CI
-  monotonicity gate) measure.
+  NIC drains at most one token per flow (one connection) per step.
+  Offered load beyond that capacity queues in the rings: TTFT/ITL
+  tails CLIMB with rate, which is what the fig12 lm_decode
+  latency-vs-load rows (and their CI monotonicity gate) measure.
 """
 from __future__ import annotations
 
@@ -35,9 +35,9 @@ TINY = REDUCED.replace(n_layers=2, d_model=64, n_heads=4, n_kv_heads=2,
 
 def backpressure_fabric_config(**overrides) -> FabricConfig:
     """Egress-constrained decode fabric: one slot per flow per step
-    leaves the NIC, so token streaming saturates at ``n_flows``
-    tokens/step and offered load beyond it queues (visible latency
-    knee)."""
+    leaves the NIC, and a connection's tokens share one flow, so token
+    streaming saturates at one token per step per tenant and offered
+    load beyond it queues (visible latency knee)."""
     kw = dict(n_flows=2, ring_entries=32, batch_size=1,
               dynamic_batching=False)
     kw.update(overrides)

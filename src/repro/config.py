@@ -314,11 +314,13 @@ class TrainConfig:
 
 # Each profile: env vars set BEFORE jax import (setdefault — an explicit
 # user environment always wins) plus XLA flags APPENDED to XLA_FLAGS.
-# The accelerator profiles enable the latency-hiding scheduler and async
+# The gpu profile enables the latency-hiding scheduler and async
 # collectives so the switch step's exchange collectives overlap with the
-# per-tier compute (the knobs the fused-switch benchmarks assume on real
-# hardware); the cpu profile pins the host platform so container GPUs
-# never surprise a reproduction run.
+# per-tier compute; the cpu profile pins the host platform so container
+# GPUs never surprise a reproduction run.  The tpu profile adds no
+# flags: a TPU takes compiler flags through LIBTPU_INIT_ARGS (which may
+# already hold workarounds and is only ever appended to), never through
+# XLA_FLAGS, where libtpu flags abort the process.
 ACCEL_PROFILES = {
     "cpu": {
         "env": {"JAX_PLATFORMS": "cpu", "JAX_ENABLE_X64": "0"},
@@ -333,11 +335,7 @@ ACCEL_PROFILES = {
     },
     "tpu": {
         "env": {"JAX_ENABLE_X64": "0"},
-        "xla_flags": [
-            "--xla_tpu_enable_latency_hiding_scheduler=true",
-            "--xla_enable_async_all_gather=true",
-            "--xla_enable_async_collective_permute=true",
-        ],
+        "xla_flags": [],
     },
 }
 
@@ -368,15 +366,57 @@ def apply_accel_profile(name: str) -> dict:
     return prof
 
 
-# Roofline hardware model (TPU v5e target, per assignment).
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its
+    directory.  ``JAX_COMPILATION_CACHE_DIR``, when set, is the cache
+    (JAX reads it itself; no other directory is set here); otherwise a
+    fixed ``<repo>/.jax_cache``.  A fixed path matters: the path is part
+    of the cache key, so a directory that moves never hits."""
+    import os
+    from pathlib import Path
+
+    import jax
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = str(Path(__file__).resolve().parents[2] / ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+# ---------------------------------------------------------------------------
+# Device peaks (roofline model)
+# ---------------------------------------------------------------------------
+
 @dataclass(frozen=True)
 class HWSpec:
-    name: str = "tpu_v5e"
-    peak_flops_bf16: float = 197e12      # per chip
-    hbm_bw: float = 819e9                # bytes/s per chip
-    ici_bw_per_link: float = 50e9        # bytes/s per link
-    hbm_bytes: float = 16e9              # capacity per chip
-    vmem_bytes: float = 128 * 2 ** 20
+    """Published per-chip peaks of one accelerator kind."""
+    name: str
+    peak_flops_bf16: float               # FLOP/s
+    hbm_bw: float                        # bytes/s
+    hbm_bytes: float                     # capacity
+    ici_bw_per_link: float               # bytes/s per chip-to-chip link
+    vmem_bytes: float
+    source: str
 
 
-HW = HWSpec()
+# Keyed by ``jax.Device.device_kind``.  A kind missing here is an error
+# (``hw_spec``), never a default.
+HW_BY_KIND = {
+    "TPU v5 lite": HWSpec(
+        name="tpu_v5e", peak_flops_bf16=197e12, hbm_bw=819e9,
+        hbm_bytes=16e9, ici_bw_per_link=50e9, vmem_bytes=128 * 2 ** 20,
+        source='Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, '
+               '16 GB HBM at 819 GB/s, 1,600 Gbit/s ICI over 4 links; '
+               'VMEM 128 MiB per core (Pallas TPU info)'),
+}
+
+
+def hw_spec(device_kind: str) -> HWSpec:
+    """Peaks of the device kind ``device_kind``; raises for a kind with
+    no entry in ``HW_BY_KIND``."""
+    try:
+        return HW_BY_KIND[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no peak table entry for device kind {device_kind!r} "
+            f"(known: {sorted(HW_BY_KIND)})") from None

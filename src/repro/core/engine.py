@@ -726,9 +726,9 @@ class ShardedTenantEngine:
                  handler: Callable, mesh=None, axis: str = "tenant",
                  stateful: bool = False, donate: bool = True,
                  loadgen=None):
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec
 
+        from repro.core.transport import shard_map
         from repro.debug import sanitize
         sanitize.note_unsanitized_sharded("ShardedTenantEngine")
         if mesh is None:
@@ -813,8 +813,7 @@ class ShardedTenantEngine:
                      self._specs(hstate))
             return self._shard_map(
                 local_steps, mesh=self.mesh, in_specs=specs,
-                out_specs=(*specs, self._P(self.axis)),
-                check_rep=False)(cst, sst, hstate)
+                out_specs=(*specs, self._P(self.axis)))(cst, sst, hstate)
 
         return run_steps
 
@@ -834,8 +833,8 @@ class ShardedTenantEngine:
             return self._shard_map(
                 local_until, mesh=self.mesh,
                 in_specs=(*sspec, lane, lane),
-                out_specs=(*sspec, lane, lane),
-                check_rep=False)(cst, sst, hstate, target, max_steps)
+                out_specs=(*sspec, lane, lane))(cst, sst, hstate, target,
+                                                max_steps)
 
         return run_until
 
@@ -867,9 +866,7 @@ class ShardedTenantEngine:
             return self._shard_map(
                 local_until, mesh=self.mesh,
                 in_specs=(*sspec, repl, repl),
-                out_specs=outs,
-                check_rep=False)(cst, sst, hstate, global_target,
-                                 max_steps)
+                out_specs=outs)(cst, sst, hstate, global_target, max_steps)
 
         return run_until_global
 

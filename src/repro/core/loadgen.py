@@ -109,6 +109,14 @@ class LoadGenState:
                             # (last bin overflows); sum == step always
 
 
+def seed_key(seed: int) -> int:
+    """An outside seed (any Python int) as the int32 lane key: reduced
+    mod 2**32 and read as two's complement, so every seed has a key
+    and seeds equal mod 2**32 share one."""
+    k = int(seed) % (1 << 32)
+    return k - (1 << 32) if k >= (1 << 31) else k
+
+
 def rate_q16(rate: float) -> int:
     """Offered rate in requests/step -> the Q16.16 register value."""
     return int(round(rate * RATE_ONE))
@@ -217,7 +225,8 @@ class LoadGen:
         """Fresh scalar generator state at ``rate`` requests/step."""
         z = jnp.int32(0)
         return LoadGenState(
-            key=jnp.int32(seed), step=z, rate=jnp.int32(rate_q16(rate)),
+            key=jnp.int32(seed_key(seed)), step=z,
+            rate=jnp.int32(rate_q16(rate)),
             acc=z, burst_on=jnp.int32(1), conn=jnp.int32(conn),
             next_rpc=z, offered=z, injected=z, dropped=z,
             arr_hist=jnp.zeros((ARR_BINS,), jnp.int32))
@@ -237,7 +246,8 @@ class LoadGen:
             raise ValueError("rates/seeds/conns must have equal length")
         z = jnp.zeros((n,), jnp.int32)
         return LoadGenState(
-            key=jnp.asarray(seeds, jnp.int32), step=z,
+            key=jnp.asarray([seed_key(s) for s in seeds], jnp.int32),
+            step=z,
             rate=jnp.asarray([rate_q16(r) for r in rates], jnp.int32),
             acc=z, burst_on=jnp.ones((n,), jnp.int32),
             conn=jnp.asarray(conns, jnp.int32),

@@ -45,8 +45,15 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
+
+
+def shard_map(f, *, mesh, in_specs, out_specs):
+    """``jax.shard_map`` as every dataplane caller uses it: per-lane
+    state is device-varying by design, so the varying-manual-axes check
+    is off."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 # ---------------------------------------------------------------------------
@@ -174,8 +181,7 @@ def mesh_shift(tile, mesh, axis: str, offset: int = 1):
     n = mesh.shape[axis]
     specs = jax.tree.map(lambda _: P(axis), tile)
     return shard_map(lambda t: shift_tiles(t, axis, n, offset), mesh=mesh,
-                     in_specs=(specs,), out_specs=specs,
-                     check_rep=False)(tile)
+                     in_specs=(specs,), out_specs=specs)(tile)
 
 
 def mesh_all_to_all(tile, mesh, axis: str):
@@ -184,13 +190,25 @@ def mesh_all_to_all(tile, mesh, axis: str):
     across lanes (global-array view of ``all_to_all_tiles``)."""
     specs = jax.tree.map(lambda _: P(axis), tile)
     return shard_map(lambda t: all_to_all_tiles(t, axis), mesh=mesh,
-                     in_specs=(specs,), out_specs=specs,
-                     check_rep=False)(tile)
+                     in_specs=(specs,), out_specs=specs)(tile)
 
 
 # ---------------------------------------------------------------------------
 # mesh construction
 # ---------------------------------------------------------------------------
+
+def _make_mesh(shape, names):
+    """``jax.make_mesh`` (device order from the physical topology) with
+    the classic auto-sharded axes the dataplane's specs are written
+    for; ``jax.make_mesh`` raises when the host has too few devices."""
+    from jax.sharding import AxisType
+    n = 1
+    for d in shape:
+        n *= d
+    return jax.make_mesh(tuple(shape), tuple(names),
+                         axis_types=(AxisType.Auto,) * len(shape),
+                         devices=jax.devices()[:n])
+
 
 def make_tenant_mesh(n_devices: int | None = None, axis: str = "tenant"):
     """1-D mesh over the host's devices with the tenant (NIC-slot) axis.
@@ -198,10 +216,8 @@ def make_tenant_mesh(n_devices: int | None = None, axis: str = "tenant"):
     The sharded dataplane puts the stacked tenant axis on this mesh so
     each device owns whole NIC slots; on a single-device host this is a
     1-lane mesh and the sharded engines degrade to the batched ones."""
-    devs = jax.devices()
-    if n_devices is not None:
-        devs = devs[:n_devices]
-    return jax.sharding.Mesh(devs, (axis,))
+    n = len(jax.devices()) if n_devices is None else int(n_devices)
+    return _make_mesh((n,), (axis,))
 
 
 def make_grid_mesh(n_tenant: int | None = None, n_model: int | None = None,
@@ -212,9 +228,7 @@ def make_grid_mesh(n_tenant: int | None = None, n_model: int | None = None,
     tensor-parallel over the second.  Defaults split the host's devices
     as evenly as possible, favoring the tenant axis: ``n_model`` is the
     largest divisor of the device count that is <= sqrt(count)."""
-    import numpy as np
-    devs = jax.devices()
-    n = len(devs)
+    n = len(jax.devices())
     if n_tenant is None and n_model is None:
         n_model = max(d for d in range(1, int(n ** 0.5) + 1) if n % d == 0)
         n_tenant = n // n_model
@@ -222,10 +236,5 @@ def make_grid_mesh(n_tenant: int | None = None, n_model: int | None = None,
         n_model = n // int(n_tenant)
     elif n_tenant is None:
         n_tenant = n // int(n_model)
-    n_tenant, n_model = int(n_tenant), int(n_model)
-    if n_tenant * n_model > n:
-        raise ValueError(
-            f"grid mesh {n_tenant}x{n_model} needs {n_tenant * n_model} "
-            f"devices, host has {n}")
-    grid = np.asarray(devs[:n_tenant * n_model]).reshape(n_tenant, n_model)
-    return jax.sharding.Mesh(grid, (tenant_axis, model_axis))
+    return _make_mesh((int(n_tenant), int(n_model)),
+                      (tenant_axis, model_axis))

@@ -321,10 +321,10 @@ class Switch:
         fetch, exactly as in ``switch_step_stacked``; the updated
         ``gen`` is appended as the LAST return.
         """
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         from repro.core import transport
+        from repro.core.transport import shard_map
 
         if not self.homogeneous:
             raise ValueError("sharded switch step needs homogeneous tiers")
@@ -474,7 +474,7 @@ class Switch:
             out_specs.append(gspec)
         outs = shard_map(
             local, mesh=mesh, in_specs=tuple(in_specs),
-            out_specs=tuple(out_specs), check_rep=False)(*args)
+            out_specs=tuple(out_specs))(*args)
         sts, flat_r, fv = outs[:3]
         ret = (sts, (flat_r, fv))
         if with_tel:

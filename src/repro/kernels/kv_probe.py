@@ -3,12 +3,23 @@
 MICA partitions a lossy/lossless hash index across cores; Dagger steers
 requests to the owning partition in hardware (``hash_steer``) and the
 store itself does a bucket probe per GET.  On TPU the index lives in HBM
-as [n_buckets, ways] tag + [n_buckets, ways, value_words] value arrays;
-each grid program probes a tile of queries with dynamically-indexed
-loads and selects the matching way with vectorized compares (no CAM —
-the paper notes CAMs are too expensive on FPGAs too, §4.7).
+and each query copies in only the rows that hold its bucket, then selects
+the matching way with vectorized compares (no CAM — the paper notes CAMs
+are too expensive on FPGAs too, §4.7).
 
-BlockSpec: bucket table resident (VMEM tile), queries tiled along N.
+Table layout (shared with ``runtime.kvs``): every per-bucket record
+array is packed into 128-lane rows.  A bucket's ``n`` words occupy
+``stride(n)`` consecutive lanes (``n`` rounded up to a power of two), so
+a bucket never straddles a row and ``128 // stride(n)`` buckets share
+one row.  Tags (``n = ways``), keys (``n = ways * key_words``) and
+values (``n = ways * value_words``) are each ``[rows, 128]``.  The
+128-lane minor dim is the TPU's native tile width, so the arrays stay
+in HBM with no relayout and each probe is one row DMA per array.
+
+Grid: one program per tile of ``TILE_Q`` queries.  Bucket ids ride in
+SMEM (scalar prefetch) to address the DMAs; the way match (tag and
+key, like the jnp oracle) and the value select run on the VPU over the
+whole tile.
 """
 from __future__ import annotations
 
@@ -17,52 +28,126 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+LANES = 128
+TILE_Q = 128
 
 
-def _kernel(tags_ref, vals_ref, bucket_ref, qtag_ref, out_val_ref,
-            out_hit_ref, *, ways: int, tile_q: int):
-    for i in range(tile_q):                       # queries in this tile
-        b = bucket_ref[i]
-        tags = pl.load(tags_ref, (pl.dslice(b, 1), slice(None)))[0]  # [ways]
-        match = tags == qtag_ref[i]
-        hit = jnp.any(match)
-        way = jnp.argmax(match)
-        val = pl.load(vals_ref,
-                      (pl.dslice(b, 1), pl.dslice(way, 1), slice(None)))
-        out_val_ref[i, :] = jnp.where(hit, val[0, 0], 0)
-        out_hit_ref[i] = hit.astype(jnp.int32)
+def stride(n: int) -> int:
+    """Lanes one bucket's ``n`` words occupy in a packed row."""
+    s = 1 << max(n - 1, 0).bit_length()
+    if s > LANES:
+        raise ValueError(f"{n} words per bucket exceed one {LANES}-lane row")
+    return s
 
 
-@functools.partial(jax.jit, static_argnames=("tile_q", "interpret"))
-def kv_probe(tags, values, q_bucket, q_tag, tile_q: int = 8,
-             interpret: bool = True):
-    """tags [NB, WAYS] uint32; values [NB, WAYS, VW] int32;
-    q_bucket [N] int32; q_tag [N] uint32 -> (val [N, VW], hit [N] bool)."""
-    nb, ways = tags.shape
-    vw = values.shape[-1]
-    n = q_bucket.shape[0]
-    tile = min(tile_q, n)
-    pad = (-n) % tile
-    if pad:
-        q_bucket = jnp.pad(q_bucket, (0, pad))
-        q_tag = jnp.pad(q_tag, (0, pad))
+def packed_rows(n_buckets: int, n: int) -> int:
+    """Rows of the packed array holding ``n`` words for every bucket."""
+    per_row = LANES // stride(n)
+    return -(-n_buckets // per_row)
+
+
+def locate(bucket, n: int):
+    """(row, first lane) of ``bucket``'s ``n``-word record."""
+    s = stride(n)
+    per_row = LANES // s
+    return bucket // per_row, (bucket % per_row) * s
+
+
+def pack(records):
+    """Per-bucket records ``[NB, n]`` -> the packed ``[rows, 128]`` array
+    (bucket ``b`` at ``locate(b, n)``)."""
+    nb, n = records.shape
+    s = stride(n)
+    per_row = LANES // s
+    out = jnp.pad(records, ((0, (-nb) % per_row), (0, s - n)))
+    return out.reshape(-1, LANES)
+
+
+def _kernel(bucket_sm, tags_hbm, keys_hbm, vals_hbm, bucket_ref, qtag_ref,
+            qkey_ref, out_val, out_hit, tag_rows, key_rows, val_rows, sem,
+            *, ways: int, kw: int, vw: int):
+    base = pl.program_id(0) * TILE_Q
+    arrays = ((tags_hbm, tag_rows, ways), (keys_hbm, key_rows, ways * kw),
+              (vals_hbm, val_rows, ways * vw))
+
+    def copies(i):
+        b = bucket_sm[base + i]
+        return [pltpu.make_async_copy(
+                    hbm.at[pl.ds(b // (LANES // stride(n)), 1)],
+                    rows.at[pl.ds(i, 1)], sem.at[k])
+                for k, (hbm, rows, n) in enumerate(arrays)]
+
+    def start(i, c):
+        for cp in copies(i):
+            cp.start()
+        return c
+
+    def wait(i, c):
+        for cp in copies(i):
+            cp.wait()
+        return c
+
+    jax.lax.fori_loop(0, TILE_Q, start, 0)
+    jax.lax.fori_loop(0, TILE_Q, wait, 0)
+
+    b = bucket_ref[...]                                   # [TILE_Q, 1]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (TILE_Q, LANES), 1)
+
+    def word(rows, n, offset):
+        """Lane ``offset`` of each query's bucket record -> [TILE_Q, 1]."""
+        at = (b % (LANES // stride(n))) * stride(n) + offset
+        return jnp.sum(jnp.where(lane == at, rows[...], 0), axis=1,
+                       keepdims=True)
+
+    # first way whose tag AND key match (the jnp oracle's rule)
+    first = jnp.full((TILE_Q, 1), ways, jnp.int32)
+    for w in reversed(range(ways)):
+        m = word(tag_rows, ways, w) == qtag_ref[...]
+        for t in range(kw):
+            m &= word(key_rows, ways * kw, w * kw + t) == qkey_ref[:, t:t + 1]
+        first = jnp.where(m, w, first)
+    hit = first < ways
+    out = jnp.zeros((TILE_Q, LANES), jnp.int32)
+    for j in range(vw):
+        out = jnp.where(lane == j, word(val_rows, ways * vw, first * vw + j),
+                        out)
+    out_val[...] = jnp.where(hit, out, 0)
+    out_hit[...] = hit.astype(jnp.int32)
+
+
+@functools.partial(jax.jit, static_argnames=("ways", "vw", "interpret"))
+def kv_probe(tags, keys, values, q_bucket, q_tag, q_key, *, ways: int,
+             vw: int, interpret: bool):
+    """tags: packed [rows_t, 128] uint32 (``ways`` per bucket); keys:
+    packed [rows_k, 128] int32 (``ways * kw`` per bucket); values: packed
+    [rows_v, 128] int32 (``ways * vw`` per bucket); q_bucket [N] int32;
+    q_tag [N] uint32; q_key [N, kw] int32 -> (val [N, vw], hit [N] bool)
+    — the value of the first way whose tag and key both match."""
+    n, kw = q_key.shape
+    pad = (-n) % TILE_Q
+    qb = jnp.pad(q_bucket.astype(jnp.int32), (0, pad))
+    qt = jnp.pad(jax.lax.bitcast_convert_type(q_tag, jnp.int32), (0, pad))
+    qk = jnp.pad(q_key.astype(jnp.int32), ((0, pad), (0, 0)))
+    tags_i = jax.lax.bitcast_convert_type(tags, jnp.int32)
+    col = pl.BlockSpec((TILE_Q, 1), lambda i, bsm: (i, 0))
+    row = pl.BlockSpec((TILE_Q, LANES), lambda i, bsm: (i, 0))
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
     val, hit = pl.pallas_call(
-        functools.partial(_kernel, ways=ways, tile_q=tile),
-        grid=((n + pad) // tile,),
-        in_specs=[
-            pl.BlockSpec((nb, ways), lambda i: (0, 0)),
-            pl.BlockSpec((nb, ways, vw), lambda i: (0, 0, 0)),
-            pl.BlockSpec((tile,), lambda i: (i,)),
-            pl.BlockSpec((tile,), lambda i: (i,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((tile, vw), lambda i: (i, 0)),
-            pl.BlockSpec((tile,), lambda i: (i,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n + pad, vw), jnp.int32),
-            jax.ShapeDtypeStruct((n + pad,), jnp.int32),
-        ],
+        functools.partial(_kernel, ways=ways, kw=kw, vw=vw),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=((n + pad) // TILE_Q,),
+            in_specs=[hbm, hbm, hbm, col, col,
+                      pl.BlockSpec((TILE_Q, kw), lambda i, bsm: (i, 0))],
+            out_specs=[row, col],
+            scratch_shapes=[pltpu.VMEM((TILE_Q, LANES), jnp.int32)] * 3
+            + [pltpu.SemaphoreType.DMA((3,))]),
+        out_shape=[jax.ShapeDtypeStruct((n + pad, LANES), jnp.int32),
+                   jax.ShapeDtypeStruct((n + pad, 1), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
         interpret=interpret,
-    )(tags, values, q_bucket, q_tag)
-    return val[:n], hit[:n].astype(bool)
+    )(qb, tags_i, keys, values, qb[:, None], qt[:, None], qk)
+    return val[:n, :vw], hit[:n, 0] != 0

@@ -7,23 +7,29 @@ their own HBM round-trips.  On the FPGA these are ONE pipeline: an RPC
 arriving from the network is granted a request-buffer slot, steered, and
 its slot reference landed in a flow FIFO within the same cycle budget.
 
-This kernel is that pipeline as a single Pallas program.  The whole
-delivery state (free FIFO, request table, flow FIFOs, connection cache)
-lives in VMEM — rings are small by construction (E slots of one cache
-line per flow) — and a ``fori_loop`` walks the request tile once,
-carrying the arbitration registers (grant counter, leak counter, per-flow
-rank counters) exactly like the hardware's per-cycle arbiter:
+This kernel is that pipeline as a single Pallas program.  A scalar
+``fori_loop`` walks the request tile once, carrying the arbitration
+registers (grant counter, leak counter, per-flow rank counters) exactly
+like the hardware's per-cycle arbiter:
 
   row i:  grant   <- free FIFO head + #grants-so-far   (FIFO order)
           steer   <- conn cache read port 2 + FNV-1a hash / RR cursor
           scatter <- flow_fifo[flow, tail+rank] = slot  (or leak the
                      slot back to the free FIFO on backpressure)
 
+TPU adaptation: every register, index array and slot-id table (free
+FIFO, flow FIFOs, connection cache, per-row decisions) lives in SMEM,
+the scalar core's memory, where the loop reads and writes single words.
+The request table is the only vector state: it sits in VMEM transposed
+to ``[W, R]`` (slot ids along lanes, since a 16-word slot is narrower
+than the 128-lane tile), and a granted row lands as a lane-masked
+select of its slot column, picked out of the transposed ``[W, N]``
+request tile by a masked lane reduction.  Rows the arbiter rejects
+write nothing.
+
 Reads go against the *input* refs (the pre-write state — the 1W3R model),
 writes against the output refs, so in-call allocate/release overlap keeps
-the unfused semantics bit-for-bit (verified by the parity suite).  The
-dropped-row stores reuse the ``ring_push`` read-modify-write idiom: a
-rejected row stores its target's own prior contents back.
+the unfused semantics bit-for-bit (verified by the parity suite).
 
 Cursor/counter updates (free head/tail, flow-FIFO tails, RR cursor,
 monitor bumps) are cheap scalar arithmetic and stay outside the kernel in
@@ -37,6 +43,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.load_balancer import LB_OBJECT, LB_ROUND_ROBIN, LB_STATIC
 from repro.core.serdes import FLAG_RESPONSE, HEADER_WORDS
@@ -49,57 +56,82 @@ _FREE_HEAD, _FREE_AVAIL, _FREE_TAIL, _RR0, _ACTIVE = range(5)
 SCAL_WORDS = 5
 
 
-def _kernel(slots_ref, valid_ref, fifo_ref, req_ref, ffbuf_ref,
-            tag_ref, src_ref, lb_ref, fftail_ref, ffspace_ref, scal_ref,
-            req_out, ffbuf_out, fifo_out, sid_out, flow_out, granted_out,
-            accepted_out, acc_out, ctr_out, *, key_words: int):
-    req_out[...] = req_ref[...]
-    ffbuf_out[...] = ffbuf_ref[...]
-    fifo_out[...] = fifo_ref[...]
+def _shr(x, k: int):
+    return jax.lax.shift_right_logical(x, jnp.int32(k))
 
-    n = slots_ref.shape[0]
+
+def _umod(h, a):
+    """``h mod a`` with ``h`` read as uint32 — in int32 arithmetic, which
+    the scalar core supports (``a`` is small and positive)."""
+    return ((_shr(h, 1) % a) * 2 + (h & 1)) % a
+
+
+def _kernel(hdr_ref, valid_ref, fifo_ref, tag_ref, src_ref, lb_ref,
+            fftail_ref, ffspace_ref, scal_ref, ffbuf_ref, slots_ref,
+            req_ref, req_out, ffbuf_out, fifo_out, sid_out, flow_out,
+            granted_out, accepted_out, acc_out, ctr_out, g_counts, *,
+            key_words: int):
+    req_out[...] = req_ref[...]
+    w, n = slots_ref.shape
     r_cap = fifo_ref.shape[0]                      # request buffer slots
     n_conn = tag_ref.shape[0]
-    n_flows = ffbuf_ref.shape[0]
-    d_cap = ffbuf_ref.shape[1]
+    n_flows = fftail_ref.shape[0]
+    d_cap = ffbuf_ref.shape[0] // n_flows
+    hw = 2 + key_words                             # header words per row
     free_head = scal_ref[_FREE_HEAD]
     free_avail = scal_ref[_FREE_AVAIL]
     free_tail = scal_ref[_FREE_TAIL]
     rr0 = scal_ref[_RR0]
     active = scal_ref[_ACTIVE]
 
+    def copy(ref_in, ref_out):
+        def body(j, c):
+            ref_out[j] = ref_in[j]
+            return c
+        jax.lax.fori_loop(0, ref_in.shape[0], body, 0)
+
+    copy(fifo_ref, fifo_out)
+    copy(ffbuf_ref, ffbuf_out)
+
+    def zero(j, c):
+        g_counts[j] = 0
+        acc_out[j] = 0
+        return c
+
+    jax.lax.fori_loop(0, n_flows, zero, 0)
+    lane_n = jax.lax.broadcasted_iota(jnp.int32, (w, n), 1)
+    lane_r = jax.lax.broadcasted_iota(jnp.int32, (w, r_cap), 1)
+
     def body(i, carry):
-        n_granted, n_leaked, n_rr, g_counts, a_counts = carry
-        row = pl.load(slots_ref, (pl.dslice(i, 1), slice(None)))[0]
+        n_granted, n_leaked, n_rr = carry
         v = valid_ref[i] != 0
 
         # ---- free-slot FIFO allocate (reads the pre-release contents) --
         granted = v & (n_granted < free_avail)
-        a_idx = (free_head + n_granted) % r_cap
-        sid = pl.load(fifo_ref, (pl.dslice(a_idx, 1),))[0]
-        sid = jnp.where(granted, sid, r_cap)       # OOB sentinel
+        sid = jnp.where(granted, fifo_ref[(free_head + n_granted) % r_cap],
+                        r_cap)                     # OOB sentinel
 
-        # ---- request-buffer write (drop via RMW of row 0) --------------
-        w_idx = jnp.where(granted, sid, 0)
-        old_req = pl.load(req_out, (pl.dslice(w_idx, 1), slice(None)))
-        pl.store(req_out, (pl.dslice(w_idx, 1), slice(None)),
-                 jnp.where(granted, row[None, :], old_req))
+        # ---- request-buffer write ---------------------------------------
+        @pl.when(granted)
+        def _():
+            col = jnp.sum(jnp.where(lane_n == i, slots_ref[...], 0),
+                          axis=1, keepdims=True)             # [W, 1]
+            req_out[...] = jnp.where(lane_r == sid, col, req_out[...])
 
         # ---- connection lookup (1W3R read port 2) + steering -----------
-        cid = row[0]
+        cid = hdr_ref[i * hw]
         c_idx = cid % n_conn
-        hit = pl.load(tag_ref, (pl.dslice(c_idx, 1),))[0] == cid
-        srcf = pl.load(src_ref, (pl.dslice(c_idx, 1),))[0]
-        lbv = pl.load(lb_ref, (pl.dslice(c_idx, 1),))[0]
-        flags = (row[2] >> 16) & 0xFFFF
+        hit = tag_ref[c_idx] == cid
+        srcf = src_ref[c_idx]
+        lbv = lb_ref[c_idx]
+        flags = _shr(hdr_ref[i * hw + 1], 16) & 0xFFFF
         is_resp = (flags & FLAG_RESPONSE) != 0
-        h = jnp.uint32(FNV_OFFSET)
+        h = jnp.int32(FNV_OFFSET - (1 << 32))
         for k in range(key_words):
-            wk = row[HEADER_WORDS + k].astype(jnp.uint32)
+            wk = hdr_ref[i * hw + 2 + k]
             for shift in (0, 8, 16, 24):
-                byte = (wk >> shift) & jnp.uint32(0xFF)
-                h = (h ^ byte) * jnp.uint32(FNV_PRIME)
-        obj = (h % active.astype(jnp.uint32)).astype(jnp.int32)
+                h = (h ^ (_shr(wk, shift) & 0xFF)) * jnp.int32(FNV_PRIME)
+        obj = _umod(h, active)
         # RR positions are cumulative over the VALID ROUND_ROBIN rows
         # only: n_rr is the carried count of such rows before this one,
         # so mixed-scheme batches and partially-valid tiles fill RR
@@ -113,50 +145,40 @@ def _kernel(slots_ref, valid_ref, fifo_ref, req_ref, ffbuf_ref,
 
         # ---- flow-FIFO push arbitration --------------------------------
         rank = g_counts[flow]
-        space = pl.load(ffspace_ref, (pl.dslice(flow, 1),))[0]
-        tailf = pl.load(fftail_ref, (pl.dslice(flow, 1),))[0]
-        accepted = granted & (rank < space)
-        pos = (tailf + rank) % d_cap
-        qs = jnp.where(accepted, flow, 0)
-        ps = jnp.where(accepted, pos, 0)
-        old_ff = pl.load(ffbuf_out, (pl.dslice(qs, 1), pl.dslice(ps, 1)))
-        pl.store(ffbuf_out, (pl.dslice(qs, 1), pl.dslice(ps, 1)),
-                 jnp.where(accepted, sid, old_ff[0, 0])[None, None])
+        accepted = granted & (rank < ffspace_ref[flow])
+
+        @pl.when(accepted)
+        def _():
+            ffbuf_out[flow * d_cap + (fftail_ref[flow] + rank) % d_cap] = sid
 
         # ---- FIFO full: leak the granted slot back to the free FIFO ----
         leaked = granted & ~accepted
-        l_idx = jnp.where(leaked, (free_tail + n_leaked) % r_cap, 0)
-        old_f = pl.load(fifo_out, (pl.dslice(l_idx, 1),))
-        pl.store(fifo_out, (pl.dslice(l_idx, 1),),
-                 jnp.where(leaked, sid, old_f[0])[None])
+
+        @pl.when(leaked)
+        def _():
+            fifo_out[(free_tail + n_leaked) % r_cap] = sid
 
         # ---- per-row decisions ----------------------------------------
-        pl.store(sid_out, (pl.dslice(i, 1),), sid[None])
-        pl.store(flow_out, (pl.dslice(i, 1),), flow[None])
-        pl.store(granted_out, (pl.dslice(i, 1),),
-                 granted.astype(jnp.int32)[None])
-        pl.store(accepted_out, (pl.dslice(i, 1),),
-                 accepted.astype(jnp.int32)[None])
-
-        g_counts = g_counts.at[flow].add(granted.astype(jnp.int32))
-        a_counts = a_counts.at[flow].add(accepted.astype(jnp.int32))
+        sid_out[i] = sid
+        flow_out[i] = flow
+        granted_out[i] = granted.astype(jnp.int32)
+        accepted_out[i] = accepted.astype(jnp.int32)
+        g_counts[flow] = rank + granted.astype(jnp.int32)
+        acc_out[flow] = acc_out[flow] + accepted.astype(jnp.int32)
         return (n_granted + granted.astype(jnp.int32),
-                n_leaked + leaked.astype(jnp.int32), n_rr,
-                g_counts, a_counts)
+                n_leaked + leaked.astype(jnp.int32), n_rr)
 
-    carry = (jnp.int32(0), jnp.int32(0), jnp.int32(0),
-             jnp.zeros((n_flows,), jnp.int32),
-             jnp.zeros((n_flows,), jnp.int32))
-    n_granted, n_leaked, n_rr, _, a_counts = jax.lax.fori_loop(
-        0, n, body, carry)
-    acc_out[...] = a_counts
-    ctr_out[...] = jnp.stack([n_granted, n_leaked, n_rr])
+    n_granted, n_leaked, n_rr = jax.lax.fori_loop(
+        0, n, body, (jnp.int32(0), jnp.int32(0), jnp.int32(0)))
+    ctr_out[0] = n_granted
+    ctr_out[1] = n_leaked
+    ctr_out[2] = n_rr
 
 
 @functools.partial(jax.jit, static_argnames=("key_words", "interpret"))
 def nic_deliver_fused(slots, valid, fifo, req_table, ffbuf, conn_tag,
                       conn_src, conn_lb, fftail, ffspace, scal,
-                      key_words: int = 2, interpret: bool = True):
+                      key_words: int = 2, *, interpret: bool):
     """One fused steer+allocate+scatter pass over a request tile.
 
     slots [N, W], valid [N] int32; fifo [R] free-slot ids; req_table
@@ -170,11 +192,14 @@ def nic_deliver_fused(slots, valid, fifo, req_table, ffbuf, conn_tag,
     """
     n, w = slots.shape
     r, f, d = fifo.shape[0], ffbuf.shape[0], ffbuf.shape[1]
-    c = conn_tag.shape[0]
-    whole = lambda *shape: pl.BlockSpec(shape, lambda i: (0,) * len(shape))
+    hdr = jnp.concatenate(
+        [slots[:, :1], slots[:, 2:3],
+         slots[:, HEADER_WORDS:HEADER_WORDS + key_words]], axis=1)
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
     out_shape = (
-        jax.ShapeDtypeStruct((r, w), jnp.int32),       # req_table'
-        jax.ShapeDtypeStruct((f, d), jnp.int32),       # ffbuf'
+        jax.ShapeDtypeStruct((w, r), jnp.int32),       # req_table'^T
+        jax.ShapeDtypeStruct((f * d,), jnp.int32),     # ffbuf'
         jax.ShapeDtypeStruct((r,), jnp.int32),         # fifo'
         jax.ShapeDtypeStruct((n,), jnp.int32),         # slot_ids
         jax.ShapeDtypeStruct((n,), jnp.int32),         # flow
@@ -183,24 +208,15 @@ def nic_deliver_fused(slots, valid, fifo, req_table, ffbuf, conn_tag,
         jax.ShapeDtypeStruct((f,), jnp.int32),         # accepted per flow
         jax.ShapeDtypeStruct((3,), jnp.int32),         # counters
     )
-    return pl.pallas_call(
+    outs = pl.pallas_call(
         functools.partial(_kernel, key_words=key_words),
-        grid=(1,),
-        in_specs=[
-            whole(n, w),          # slots
-            whole(n),             # valid
-            whole(r),             # free fifo
-            whole(r, w),          # request table
-            whole(f, d),          # flow fifo buf
-            whole(c),             # conn tag
-            whole(c),             # conn src_flow
-            whole(c),             # conn lb
-            whole(f),             # flow fifo tails
-            whole(f),             # flow fifo free space
-            whole(SCAL_WORDS),    # scalar registers
-        ],
-        out_specs=tuple(whole(*s.shape) for s in out_shape),
+        in_specs=[smem] * 10 + [vmem, vmem],
+        out_specs=(vmem,) + (smem,) * 8,
         out_shape=out_shape,
+        scratch_shapes=[pltpu.SMEM((f,), jnp.int32)],   # per-flow ranks
+        input_output_aliases={11: 0},
         interpret=interpret,
-    )(slots, valid, fifo, req_table, ffbuf, conn_tag, conn_src, conn_lb,
-      fftail, ffspace, scal)
+    )(hdr.reshape(-1), valid, fifo, conn_tag, conn_src, conn_lb, fftail,
+      ffspace, scal, ffbuf.reshape(-1), slots.T, req_table.T)
+    req_t, ffb = outs[0], outs[1]
+    return (req_t.T, ffb.reshape(f, d)) + tuple(outs[2:])

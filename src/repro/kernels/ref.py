@@ -211,15 +211,17 @@ def ref_rpc_pack(conn_id, rpc_id, fn_id, flags, payload_len, frag_idx,
         axis=-1).astype(jnp.int32)
 
 
-def ref_kv_probe(tags, values, q_bucket, q_tag):
+def ref_kv_probe(tags, keys, values, q_bucket, q_tag, q_key):
     """Set-associative probe.
 
-    tags: [NB, WAYS] uint32 (0 = empty); values: [NB, WAYS, VW] int32;
-    q_bucket: [N] int32; q_tag: [N] uint32.
-    Returns (value [N, VW] int32, hit [N] bool).
+    tags: [NB, WAYS] uint32 (0 = empty); keys: [NB, WAYS, KW] int32;
+    values: [NB, WAYS, VW] int32; q_bucket: [N] int32; q_tag: [N]
+    uint32; q_key: [N, KW] int32.  A way matches when its tag and key
+    both do.  Returns (value [N, VW] of the first matching way, else 0;
+    hit [N] bool).
     """
-    bt = tags[q_bucket]                       # [N, WAYS]
-    match = bt == q_tag[:, None]
+    match = (tags[q_bucket] == q_tag[:, None]) & jnp.all(
+        keys[q_bucket] == q_key[:, None, :], axis=-1)
     hit = jnp.any(match, axis=1)
     way = jnp.argmax(match, axis=1)
     val = values[q_bucket, way]

@@ -69,6 +69,15 @@ SCAL_COLS = 9
  M_FIFO_FULL, M_BATCHES) = range(7)
 MON_COLS = 7
 
+# What the v5e compiler says to this kernel (pinned by
+# tests/test_tpu_compile.py): the 4-D ``take_along_axis`` ring reads and
+# the ``.at[]`` scatters of phases A-D have no Mosaic lowering.  Until
+# the kernel is redesigned around 2-D gathers, a TPU refuses the fused
+# switch path instead of silently running the jnp composition.
+MOSAIC_REFUSAL = ("switch_step_fused does not compile for TPU — Mosaic: "
+                  "'NotImplementedError: Only 2D gather is supported'. "
+                  "Run the switch path with use_pallas=False on a TPU.")
+
 
 def _fnv1a_rows(rows, key_words: int):
     """Vectorized byte-serial FNV-1a over the payload key words [M]."""
@@ -308,8 +317,8 @@ def switch_step_fused(tx_buf, tx_head, tx_tail, rx_buf, rx_head, rx_tail,
                       req_table, fifo, ffbuf, ff_head, ff_tail,
                       conn_tag, conn_src, conn_dest, conn_lb, scal, hist,
                       ext_slots, ext_valid, ext_dest, bmax: int,
-                      include_fetch: bool = True, key_words: int = 2,
-                      interpret: bool = True):
+                      include_fetch: bool = True, key_words: int = 2, *,
+                      interpret: bool):
     """One fused fetch+steer+deliver+emit+drain pass over a tier stack.
 
     tx/rx rings [T, F, E, W] with head/tail [T, F]; req_table [T, R, W];
@@ -324,6 +333,23 @@ def switch_step_fused(tx_buf, tx_head, tx_tail, rx_buf, rx_head, rx_tail,
     cand_valid [M], cand_dest [M], drained [T, F*bmax, W],
     dvalid [T, F*bmax], mon [T, MON_COLS]).
     """
+    if not interpret:
+        raise NotImplementedError(MOSAIC_REFUSAL)
+    return fused_call(tx_buf, tx_head, tx_tail, rx_buf, rx_head, rx_tail,
+                      req_table, fifo, ffbuf, ff_head, ff_tail, conn_tag,
+                      conn_src, conn_dest, conn_lb, scal, hist, ext_slots,
+                      ext_valid, ext_dest, bmax=bmax,
+                      include_fetch=include_fetch, key_words=key_words,
+                      interpret=interpret)
+
+
+def fused_call(tx_buf, tx_head, tx_tail, rx_buf, rx_head, rx_tail,
+               req_table, fifo, ffbuf, ff_head, ff_tail, conn_tag,
+               conn_src, conn_dest, conn_lb, scal, hist, ext_slots,
+               ext_valid, ext_dest, *, bmax: int, include_fetch: bool,
+               key_words: int, interpret: bool):
+    """The ``pallas_call`` behind ``switch_step_fused``, unguarded (the
+    v5e compile test uses it to confirm Mosaic still refuses it)."""
     t, f, e, w = tx_buf.shape
     e_rx = rx_buf.shape[2]
     r = fifo.shape[1]

@@ -1,6 +1,12 @@
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# ^ MUST precede every other import (jax locks device count on first init).
+# MUST precede every other import (jax locks platform and device count on
+# first init).  The dry run compiles a modelled mesh on 512 host devices
+# and never touches an accelerator: with ``--all`` it starts one child
+# per cell, and a parent holding a TPU would lock the children out.
+os.environ["JAX_PLATFORMS"] = "cpu"
+os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
+                           " --xla_force_host_platform_device_count=512"
+                           ).strip()
 """Multi-pod dry-run: lower + compile every (arch x shape) cell on the
 production mesh and extract the roofline terms from the compiled artifact.
 
@@ -27,7 +33,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.config import HW, SHAPES, ModelConfig, ShapeCell, TrainConfig
+from repro.config import SHAPES, ModelConfig, ShapeCell, TrainConfig, hw_spec
 from repro.configs import all_arch_names, get_config
 from repro.launch.mesh import dp_axes, make_production_mesh
 from repro.models import Model
@@ -37,6 +43,8 @@ from repro.parallel import (batch_specs, cache_specs, legalize_specs,
 from repro.launch.analysis import model_flops
 from repro.runtime.train_loop import make_train_step
 
+# the modelled production mesh is a v5e pod (this host only compiles)
+HW = hw_spec("TPU v5 lite")
 RESULTS_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "..",
                            "results", "dryrun")
 

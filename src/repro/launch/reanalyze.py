@@ -12,12 +12,14 @@ import gzip
 import json
 import os
 
-from repro.config import HW, SHAPES
+from repro.config import SHAPES, hw_spec
 from repro.configs import get_config
 from repro.launch.analysis import model_flops
 from repro.launch.hlo_cost import analyze
 
 ROOT = os.path.join(os.path.dirname(__file__), "..", "..", "..", "results")
+# the production mesh these HLOs were compiled for is a v5e pod
+HW = hw_spec("TPU v5 lite")
 
 
 def reanalyze_file(path: str):

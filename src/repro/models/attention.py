@@ -242,14 +242,11 @@ def gqa_decode(cfg: ModelConfig, p, x, cache_k, cache_v, pos):
     s = cache_k.shape[1]
     if (cfg.use_pallas and cfg.logit_softcap == 0
             and s % min(256, s) == 0):
-        # flash-decoding Pallas kernel, one [1,·] row per slot so each
+        # flash-decoding Pallas kernel with a per-row length, so each
         # slot attends its OWN valid prefix (continuous batching); the
         # jnp branch below is the oracle (tests/test_kernels.py)
         from repro.kernels import ops as kops
-        out = jax.vmap(
-            lambda q1, k1, v1, l1: kops.decode_attention(
-                q1[None], k1[None], v1[None], l1)[0]
-        )(q[:, 0], cache_k, cache_v, pv + 1)
+        out = kops.decode_attention(q[:, 0], cache_k, cache_v, pv + 1)
         out = out[:, None].astype(q.dtype)
     else:
         mask = (jnp.arange(s)[None, :] <= pv[:, None])

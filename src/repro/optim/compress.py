@@ -19,6 +19,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from repro.core.transport import shard_map
+
 
 def int8_ef_compress(g, err):
     """(g + err) -> (q int8, scale f32, new_err).  Per-tensor scale."""
@@ -66,7 +68,6 @@ def pod_sync_step(grads, err_state, mesh, axis: str = "pod"):
                 jax.tree.map(lambda _: P(), err_state))
     out_specs = (jax.tree.map(lambda _: P(), grads),
                  jax.tree.map(lambda _: P(), err_state))
-    synced = jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, check_vma=False)(
-        grads, err_state)
+    synced = shard_map(fn, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs)(grads, err_state)
     return synced

@@ -283,7 +283,13 @@ class DecodeEngine:
                                       jnp.zeros((n,), jnp.int32), flags,
                                       pay, frag_idx=slots.emitted,
                                       timestamp=slots.tstamp)
-            sst, acc = fab.host_tx_enqueue(sst, out, slots.flow, gen)
+            # a connection's replies leave on the one server flow its
+            # connection names, the flow the client NIC steers them
+            # into: the client drains batch_size of them per step, and
+            # the server's TX back-pressure now holds the stream to
+            # that rate instead of the client NIC dropping tokens
+            eflow, _, _ = sst.conn.read_flow(slots.conn)
+            sst, acc = fab.host_tx_enqueue(sst, out, eflow, gen)
             acc = acc & gen
 
             # 4. telemetry at the acceptance edge (the egress decision)
@@ -352,7 +358,8 @@ class DecodeEngine:
                 jnp.zeros_like(req["rpc_id"]),
                 serdes.FLAG_RESPONSE | serdes.FLAG_LAST_FRAGMENT
                 | (r_flow << 8), npay, timestamp=req["timestamp"])
-            sst, _ = fab.host_tx_enqueue(sst, nack, r_flow, rej)
+            nflow, _, _ = sst.conn.read_flow(req["conn_id"])
+            sst, _ = fab.host_tx_enqueue(sst, nack, nflow, rej)
 
             ttft = tlm.tick(ttft)
             itl = tlm.tick(itl)
@@ -444,9 +451,9 @@ class DecodeEngine:
         deterministic dataplane.  Same signature/returns as
         ``make_tenant_run_steps``; the tenant count must divide the
         tenant axis."""
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
+        from repro.core.transport import shard_map
         from repro.debug import sanitize
         from repro.parallel.sharding import (decode_cache_specs,
                                              legalize_specs, param_specs)
@@ -488,8 +495,7 @@ class DecodeEngine:
                 mesh)
             tile = P(None, t_axis)
             return shard_map(local, mesh=mesh, in_specs=(sspec, pspec),
-                             out_specs=(sspec, (tile, tile)),
-                             check_rep=False)(st, params)
+                             out_specs=(sspec, (tile, tile)))(st, params)
 
         fn = jax.jit(run, donate_argnums=(0,))
 

@@ -356,9 +356,9 @@ class ServingEngine:
         scalars are appended to the public signature.  States donate,
         weights stay replicated, tiles are sharded on their tenant dim.
         """
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
+        from repro.core.transport import shard_map
         from repro.debug import sanitize
         sanitize.note_unsanitized_sharded("ServingEngine (sharded)")
 
@@ -372,9 +372,8 @@ class ServingEngine:
                           tile, tile) + (P(),) * n_scalar_args,
                 out_specs=(shard(fst), shard(cache), shard(sess),
                            P(axis)) + (P(axis),) * n_device_outs
-                          + (tile, tile),
-                check_rep=False)(fst, cache, sess, params, in_slots,
-                                 in_valid, *scalars)
+                          + (tile, tile))(fst, cache, sess, params,
+                                          in_slots, in_valid, *scalars)
 
         fn = jax.jit(run, donate_argnums=(0, 1, 2))
 

@@ -10,7 +10,7 @@ unbound axis names, not divergent schedules):
 """
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from repro.core.transport import shard_map
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
@@ -27,7 +27,7 @@ def _divergent_cond():
                             x)
 
     fn = jax.jit(shard_map(local, mesh=mesh, in_specs=(P(),),
-                           out_specs=P(), check_rep=False))
+                           out_specs=P()))
     return dict(fn=fn, args=(jax.ShapeDtypeStruct((4,), jnp.int32),),
                 expect_donation=False)
 
@@ -42,7 +42,7 @@ def _local_predicate_while():
         return jax.lax.while_loop(lambda c: c[0] < 5, body, x)
 
     fn = jax.jit(shard_map(local, mesh=mesh, in_specs=(P(),),
-                           out_specs=P(), check_rep=False))
+                           out_specs=P()))
     return dict(fn=fn, args=(jax.ShapeDtypeStruct((4,), jnp.int32),),
                 expect_donation=False)
 

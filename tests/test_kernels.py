@@ -172,24 +172,43 @@ def test_rpc_pack_matches_serdes_with_fragments():
                                   np.arange(n) + 1000)
 
 
-@pytest.mark.parametrize("nb,ways,vw,n", [(8, 2, 4, 4), (64, 4, 8, 16),
-                                          (16, 8, 2, 33)])
-def test_kv_probe_sweep(nb, ways, vw, n):
+@pytest.mark.parametrize("nb,ways,vw,n,dup", [(8, 2, 4, 4, False),
+                                              (64, 4, 8, 16, False),
+                                              (16, 8, 2, 33, False),
+                                              (64, 4, 8, 130, True)])
+def test_kv_probe_sweep(nb, ways, vw, n, dup):
+    """Packed-table probe vs the logical-layout oracle.  ``dup`` gives
+    every bucket's ways 0 and 1 the same tag with different keys (two
+    keys whose 32-bit hashes collide): only the key tells them apart."""
+    from repro.kernels.kv_probe import pack
+    kw = 2
     tags = jax.random.randint(KEY, (nb, ways), 1, 2**31 - 1,
                               jnp.int32).astype(jnp.uint32)
+    if dup:
+        tags = tags.at[:, 1].set(tags[:, 0])
+    keys = jax.random.randint(jax.random.PRNGKey(5), (nb, ways, kw),
+                              -1000, 1000, jnp.int32)
     vals = jax.random.randint(jax.random.PRNGKey(1), (nb, ways, vw),
                               0, 1000, jnp.int32)
     qb = jax.random.randint(jax.random.PRNGKey(2), (n,), 0, nb, jnp.int32)
+    qw = jax.random.randint(jax.random.PRNGKey(3), (n,), 0, ways, jnp.int32)
+    if dup:
+        qw = jnp.ones_like(qw)
     # half the queries hit, half miss
-    hit_tags = tags[qb, jax.random.randint(jax.random.PRNGKey(3), (n,),
-                                           0, ways, jnp.int32)]
     miss = jax.random.randint(jax.random.PRNGKey(4), (n,), 0, 2,
                               jnp.int32) == 0
-    qt = jnp.where(miss, jnp.uint32(0xDEADBEEF), hit_tags)
-    av, ah = ops.kv_probe(tags, vals, qb, qt)
-    bv, bh = ref.ref_kv_probe(tags, vals, qb, qt)
+    qt = jnp.where(miss, jnp.uint32(0xDEADBEEF), tags[qb, qw])
+    qk = keys[qb, qw]
+    av, ah = ops.kv_probe(pack(tags), pack(keys.reshape(nb, ways * kw)),
+                          pack(vals.reshape(nb, ways * vw)), qb, qt, qk,
+                          ways=ways, vw=vw)
+    bv, bh = ref.ref_kv_probe(tags, keys, vals, qb, qt, qk)
     np.testing.assert_array_equal(np.asarray(av), np.asarray(bv))
     np.testing.assert_array_equal(np.asarray(ah), np.asarray(bh))
+    if dup:
+        assert bool(bh.any())
+        np.testing.assert_array_equal(np.asarray(av)[np.asarray(bh)],
+                                      np.asarray(vals[qb, 1])[np.asarray(bh)])
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])

@@ -9,7 +9,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from hypothesis import given, settings, strategies as st
+from hypothesis import example, given, settings, strategies as st
 
 from repro.config import FabricConfig
 from repro.core import monitor, serdes
@@ -508,6 +508,9 @@ def test_telemetry_histogram_conservation(seed, n_tenants, k):
        st.integers(1, 40),              # fused steps
        st.sampled_from([0, 1, 2]))      # arrival mode
 @settings(max_examples=10, deadline=None)
+# a seed past int32: the generator key is the seed mod 2**32
+@example(seed=2_147_483_648, n_flows=1, batch=1, entries=4, slots=0,
+         rate_x=0.1, k=1, mode=0)
 def test_loadgen_conservation_property(seed, n_flows, batch, entries,
                                        slots, rate_x, k, mode):
     """Open-loop arrival conservation, any config x any rate INCLUDING

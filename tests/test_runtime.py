@@ -163,6 +163,37 @@ def test_kvs_get_after_set_property():
     np.testing.assert_array_equal(np.asarray(got)[h], vals[h])
 
 
+@pytest.mark.parametrize("nb,ways,use_pallas", [(8, 4, False), (8, 4, True),
+                                                 (64, 2, False)])
+def test_kvs_batch_conflicts_keep_last_write(nb, ways, use_pallas):
+    """Batches with duplicate keys and more new keys per bucket than
+    free ways: a GET hit always returns the value last SET for that key
+    (tag, key and value of a slot come from one row), never-set keys
+    miss, and every stored key that misses is counted in ``n_evict``."""
+    kvs = DeviceKVS(n_buckets=nb, ways=ways, key_words=2, value_words=4,
+                    use_pallas=use_pallas)
+    st = kvs.init_state()
+    rng = np.random.default_rng(nb + ways)
+    last = {}
+    for _ in range(6):
+        k = rng.integers(0, 3 * nb, 40)
+        keys = np.stack([k, k * 7 + 1], 1).astype(np.int32)
+        vals = rng.integers(0, 1 << 30, (40, 4)).astype(np.int32)
+        st = kvs.set(st, jnp.asarray(keys), jnp.asarray(vals))
+        for i in range(40):
+            last[int(k[i])] = vals[i]
+    probe = np.arange(4 * nb)
+    keys = np.stack([probe, probe * 7 + 1], 1).astype(np.int32)
+    st, got, hit = kvs.get(st, jnp.asarray(keys))
+    got, hit = np.asarray(got), np.asarray(hit)
+    for i in probe:
+        if hit[i]:
+            assert int(i) in last
+            np.testing.assert_array_equal(got[i], last[int(i)])
+    misses = sum(1 for i in last if not hit[i])
+    assert 0 < misses <= int(st.n_evict)
+
+
 def test_zipf_workload_shape():
     wl = ZipfKVWorkload(n_keys=100, skew=0.99, set_fraction=0.5)
     keys, is_set, kw, vw = next(wl.batches(256))

@@ -136,6 +136,23 @@ def test_telemetry_matches_analytic_oracle():
     assert int(st.itl.n_done) == n_itl
 
 
+def test_streams_complete_when_pool_outruns_one_flow():
+    """More slots generate per step than one client flow drains
+    (8 slots > batch_size 4): the server's TX back-pressure holds each
+    connection's stream to what the client drains, so the client NIC
+    drops nothing and every finished stream carries all its tokens."""
+    eng = build_engine(n_slots=8, max_prompt=4, max_new_cap=24,
+                       mode=lg.MODE_DETERMINISTIC)
+    st, streams = _run_single(eng, rate=0.5, seed=KEY, steps=96)
+    key = int(np.asarray(st.gst.key))
+    done = _done_streams(streams)
+    assert len(done) >= 8
+    for rid, toks in done.items():
+        mnew = 1 + int(lg.counter_hash(key, rid, dec._SALT_MNEW)) % 24
+        assert len(toks) == mnew, f"request {rid}: {len(toks)} of {mnew}"
+    assert int(np.asarray(st.cst.mon["drops_no_slot"]).sum()) == 0
+
+
 def test_fragment_stream_is_mtu_shaped():
     """Tokens return as a fragmented >MTU response: frag indices are
     contiguous from 0 and only the final fragment carries
