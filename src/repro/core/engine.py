@@ -61,19 +61,21 @@ def _with_telemetry(step):
     def tstep(cst, sst, ht):
         hstate, tel = ht
         cst, sst, hstate, done, dvalid = step(cst, sst, hstate)
-        flow = None
-        if tel.hist.ndim == 2:
-            # per-flow histograms (telemetry.create_flows): attribute by
-            # the ORIGIN-flow tag in flags bits 8+ (LoadGen.inject
-            # stamps it; handlers echo flags, the response path only ORs
-            # FLAG_RESPONSE into the low bits).  The RX flow a response
-            # drains on is load-balancer-chosen — position-based
-            # attribution would just measure the balancer's spread.
-            # Untagged records (flags bits 8+ zero) bin under flow 0.
-            flow = jnp.clip(done["flags"] >> 8, 0,
-                            tel.hist.shape[0] - 1)
-        tel = tlm.observe(tel, done["timestamp"], dvalid, flow=flow)
-        tel = tlm.tick(tel)
+        with jax.named_scope(tlm.SCOPE_TELEMETRY):
+            flow = None
+            if tel.hist.ndim == 2:
+                # per-flow histograms (telemetry.create_flows): attribute
+                # by the ORIGIN-flow tag in flags bits 8+ (LoadGen.inject
+                # stamps it; handlers echo flags, the response path only
+                # ORs FLAG_RESPONSE into the low bits).  The RX flow a
+                # response drains on is load-balancer-chosen —
+                # position-based attribution would just measure the
+                # balancer's spread.  Untagged records (flags bits 8+
+                # zero) bin under flow 0.
+                flow = jnp.clip(done["flags"] >> 8, 0,
+                                tel.hist.shape[0] - 1)
+            tel = tlm.observe(tel, done["timestamp"], dvalid, flow=flow)
+            tel = tlm.tick(tel)
         return cst, sst, (hstate, tel), done, dvalid
 
     return tstep
@@ -94,11 +96,21 @@ def _with_loadgen(step, gen):
 
     def gstep(cst, sst, hg):
         ht, gst = hg
-        cst, gst = gen.inject(cst, gst)
+        with jax.named_scope(tlm.SCOPE_LOADGEN):
+            cst, gst = gen.inject(cst, gst)
         cst, sst, ht, done, dvalid = step(cst, sst, ht)
         return cst, sst, (ht, gst), done, dvalid
 
     return gstep
+
+
+def _dispatch_span(entry):
+    """Mark the host side of an engine entry (argument handling,
+    ``unalias``, the jitted call) as the profiler span
+    ``telemetry.SPAN_ENGINE_DISPATCH``, so an idle gap of the device in a
+    trace can be told apart from the caller's own host work."""
+    return jax.profiler.annotate_function(entry,
+                                          name=tlm.SPAN_ENGINE_DISPATCH)
 
 
 def _bufptr(leaf):
@@ -286,6 +298,26 @@ class LoopbackEngine:
                 self._gen_fns[("until", wt)] = _jit_entry(
                     self._mk_run_until(g), donate_argnums=dargs)
 
+    def _steps_entry(self, hstate, tel, gen):
+        """The jitted program ``run_steps`` calls for these optional
+        arguments, and the carry it threads beside the states."""
+        hstate = hstate if self.stateful else ()
+        ht = hstate if tel is None else (hstate, tel)
+        if gen is None:
+            return (self._run_steps if tel is None
+                    else self._run_steps_tel), ht
+        return self._gen_fn("steps", tel), (ht, gen)
+
+    def lower_steps(self, cst: FabricState, sst: FabricState, n_steps: int,
+                    hstate=None, tel=None, gen=None):
+        """Lower the program ``run_steps`` runs for these arguments
+        (a ``jax.stages.Lowered``; nothing runs and nothing is donated).
+        Its compiled text names each instruction as a profiler trace of
+        the program does, with the ``telemetry.SCOPE_*`` path it ran
+        under in its ``op_name``."""
+        fn, ht = self._steps_entry(hstate, tel, gen)
+        return fn.lower(cst, sst, ht, n_steps)
+
     def _gen_fn(self, kind: str, tel):
         if self.loadgen is None:
             raise ValueError(
@@ -333,6 +365,7 @@ class LoopbackEngine:
         return run_until
 
     # ---------------------------------------------------------- public
+    @_dispatch_span
     def run_steps(self, cst: FabricState, sst: FabricState, n_steps: int,
                   hstate=None, tel=None, gen=None):
         """Run ``n_steps`` fused pipeline iterations in ONE device call.
@@ -355,19 +388,14 @@ class LoopbackEngine:
         the updated state (with its offered/injected/dropped accounting)
         is appended LAST to the returns.
         """
-        hstate = hstate if self.stateful else ()
-        ht = hstate if tel is None else (hstate, tel)
-        if gen is None:
-            fn = self._run_steps if tel is None else self._run_steps_tel
-        else:
-            fn = self._gen_fn("steps", tel)
-            ht = (ht, gen)
+        fn, ht = self._steps_entry(hstate, tel, gen)
         if self._donate:
             cst, sst, ht = unalias((cst, sst, ht))
         cst, sst, ht, done = fn(cst, sst, ht, n_steps)
         return self._returns(cst, sst, ht, (done,), tel is not None,
                              gen is not None)
 
+    @_dispatch_span
     def run_until(self, cst: FabricState, sst: FabricState, target,
                   max_steps, hstate=None, tel=None, gen=None):
         """Step until ``target`` completions (or ``max_steps``), on device.
@@ -583,6 +611,8 @@ class TenantEngine:
                     self._mk_run_until(g), donate_argnums=dargs)
 
     _gen_fn = LoopbackEngine._gen_fn
+    _steps_entry = LoopbackEngine._steps_entry
+    lower_steps = LoopbackEngine.lower_steps
 
     # ------------------------------------------------------------------
     @staticmethod
@@ -611,6 +641,7 @@ class TenantEngine:
     _returns = LoopbackEngine._returns
 
     # ---------------------------------------------------------- public
+    @_dispatch_span
     def run_steps(self, cst: FabricState, sst: FabricState, n_steps: int,
                   hstate=None, tel=None, gen=None):
         """Run ``n_steps`` fused iterations for EVERY tenant in one call.
@@ -630,19 +661,14 @@ class TenantEngine:
         generator — lane i injects at rates[i] regardless of
         completions, same parity contract — appended last.
         """
-        hstate = hstate if self.stateful else ()
-        ht = hstate if tel is None else (hstate, tel)
-        if gen is None:
-            fn = self._run_steps if tel is None else self._run_steps_tel
-        else:
-            fn = self._gen_fn("steps", tel)
-            ht = (ht, gen)
+        fn, ht = self._steps_entry(hstate, tel, gen)
         if self._donate:
             cst, sst, ht = unalias((cst, sst, ht))
         cst, sst, ht, done = fn(cst, sst, ht, n_steps)
         return self._returns(cst, sst, ht, (done,), tel is not None,
                              gen is not None)
 
+    @_dispatch_span
     def run_until(self, cst: FabricState, sst: FabricState, target,
                   max_steps, hstate=None, tel=None, gen=None):
         """Per-tenant ``run_until``: each lane steps until ITS ``target``
@@ -785,6 +811,7 @@ class ShardedTenantEngine:
                     donate_argnums=dargs)
 
     _gen_fn = LoopbackEngine._gen_fn
+    _steps_entry = LoopbackEngine._steps_entry
 
     # ------------------------------------------------------------------
     def _specs(self, tree):
@@ -879,6 +906,7 @@ class ShardedTenantEngine:
         out = tuple(shard_states(t, self.mesh, self.axis) for t in trees)
         return out if len(out) > 1 else out[0]
 
+    @_dispatch_span
     def run_steps(self, cst: FabricState, sst: FabricState, n_steps: int,
                   hstate=None, tel=None, gen=None):
         """Run ``n_steps`` fused iterations for every tenant, each device
@@ -890,19 +918,14 @@ class ShardedTenantEngine:
         bit-identical too); inputs donate.
         """
         self._check_divisible(cst)
-        hstate = hstate if self.stateful else ()
-        ht = hstate if tel is None else (hstate, tel)
-        if gen is None:
-            fn = self._run_steps if tel is None else self._run_steps_tel
-        else:
-            fn = self._gen_fn("steps", tel)
-            ht = (ht, gen)
+        fn, ht = self._steps_entry(hstate, tel, gen)
         if self._donate:
             cst, sst, ht = unalias((cst, sst, ht))
         cst, sst, ht, done = fn(cst, sst, ht, n_steps)
         return self._returns(cst, sst, ht, (done,), tel is not None,
                              gen is not None)
 
+    @_dispatch_span
     def run_until(self, cst: FabricState, sst: FabricState, target,
                   max_steps, hstate=None, tel=None, gen=None):
         """Per-tenant ``run_until`` on the mesh: each lane steps until
@@ -928,6 +951,7 @@ class ShardedTenantEngine:
         return self._returns(cst, sst, ht, (done, steps), tel is not None,
                              gen is not None)
 
+    @_dispatch_span
     def run_until_global(self, cst: FabricState, sst: FabricState,
                          global_target, max_steps, hstate=None, tel=None,
                          gen=None):

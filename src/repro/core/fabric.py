@@ -95,6 +95,7 @@ class DaggerFabric:
         )
 
     # ---------------------------------------------------------- host side
+    @jax.named_scope(tlm.SCOPE_NIC_ENQUEUE)
     def host_tx_enqueue(self, st: FabricState, records, flow_ids,
                         valid=None) -> Tuple[FabricState, jnp.ndarray]:
         """The host's single memory write: pack records into TX ring slots."""
@@ -109,6 +110,7 @@ class DaggerFabric:
         mon = monitor.bump(st.mon, drops_tx_full=rejected)
         return _replace(st, tx=tx, mon=mon), accepted
 
+    @jax.named_scope(tlm.SCOPE_NIC_DRAIN)
     def host_rx_drain(self, st: FabricState, max_n: int):
         """Completion-queue drain: read + consume RX ring entries."""
         slots, valid = st.rx.peek(max_n)
@@ -119,6 +121,7 @@ class DaggerFabric:
         return _replace(st, rx=rx, mon=mon), recs, valid
 
     # ----------------------------------------------------------- NIC side
+    @jax.named_scope(tlm.SCOPE_NIC_FETCH)
     def nic_fetch(self, st: FabricState):
         """CCI-P batched fetch from host TX rings (paper RX path).
 
@@ -133,6 +136,7 @@ class DaggerFabric:
         mon = monitor.bump(st.mon, rpcs_ingested=jnp.sum(take))
         return _replace(st, tx=tx, mon=mon), slots, valid
 
+    @jax.named_scope(tlm.SCOPE_NIC_DELIVER)
     def nic_deliver(self, st: FabricState, slots, valid, use_pallas=None):
         """Network -> request buffer -> steer -> flow FIFOs (paper TX path).
 
@@ -201,6 +205,7 @@ class DaggerFabric:
         return _replace(st, req_table=req_table, free=free, flow_fifo=ff2,
                         rr=rr, mon=mon)
 
+    @jax.named_scope(tlm.SCOPE_NIC_EMIT)
     def nic_sched_emit(self, st: FabricState):
         """Flow scheduler + CCI-P transmitter: flow FIFOs -> host RX rings."""
         c = self.cfg
@@ -254,14 +259,30 @@ class DaggerFabric:
         stacked = jax.tree.map(lambda x: x[None], st)
         ext = (slots, jnp.asarray(valid).astype(jnp.int32),
                jnp.zeros((slots.shape[0],), jnp.int32))
-        sts, flat_r, fv, _ = fused_switch_front(self, stacked, None,
-                                                ext=ext)
+        with jax.named_scope(tlm.SCOPE_NIC_PIPELINE):
+            sts, flat_r, fv, _ = fused_switch_front(self, stacked, None,
+                                                    ext=ext)
         st2 = jax.tree.map(lambda x: x[0], sts)
         bmax = c.batch_size
         recs = jax.tree.map(
             lambda x: x[0].reshape((c.n_flows, bmax) + x.shape[2:]),
             flat_r)
         return st2, recs, fv[0].reshape(c.n_flows, bmax)
+
+    @jax.named_scope(tlm.SCOPE_QUEUES)
+    def count_queues(self, st: FabricState) -> FabricState:
+        """Add each kind of ring's total occupancy to the monitor's
+        ``queued_*`` counters; called once per fused step, at its end.
+
+        At a step's end every RPC in flight waits in exactly one ring of
+        one NIC, so summed over both NICs of a loopback pair the counters
+        grow by one per RPC and step it waits: an RPC of residency L
+        (``telemetry``) adds L - 1, and after a drain the sum equals
+        ``Telemetry.sum_steps - Telemetry.n_done``."""
+        mon = monitor.bump(st.mon, queued_tx=jnp.sum(st.tx.occupancy()),
+                           queued_fifo=jnp.sum(st.flow_fifo.occupancy()),
+                           queued_rx=jnp.sum(st.rx.occupancy()))
+        return _replace(st, mon=mon)
 
     # ------------------------------------------------------ connection mgmt
     def open_connection(self, st: FabricState, c_id, src_flow, dest_addr,
@@ -383,27 +404,38 @@ def make_loopback_step_stateful(client: DaggerFabric, server: DaggerFabric,
 
     def step(cst: FabricState, sst: FabricState, hstate):
         # client NIC fetches host-written requests and puts them on the wire
-        cst, slots, valid = client.nic_fetch(cst)
+        with jax.named_scope(tlm.SCOPE_CLIENT):
+            cst, slots, valid = client.nic_fetch(cst)
         n = slots.shape[0] * slots.shape[1]
         w = slots.shape[2]
         # wire -> server NIC -> dispatch threads (deliver/emit/drain — the
         # fused megakernel back-half when the server runs use_pallas)
-        sst, reqs, rvalid = server.nic_pipeline(sst, slots.reshape(n, w),
-                                                valid.reshape(n))
+        with jax.named_scope(tlm.SCOPE_SERVER):
+            sst, reqs, rvalid = server.nic_pipeline(
+                sst, slots.reshape(n, w), valid.reshape(n))
         flat = jax.tree.map(lambda x: x.reshape((-1,) + x.shape[2:]), reqs)
         fvalid = rvalid.reshape(-1)
-        resp, hstate = handler(flat, fvalid, hstate)
+        with jax.named_scope(tlm.SCOPE_HANDLER):
+            resp, hstate = handler(flat, fvalid, hstate)
         resp["flags"] = resp["flags"] | serdes.FLAG_RESPONSE
-        # server host writes responses to its TX rings (single memory write)
-        flow_of = jnp.repeat(jnp.arange(server.cfg.n_flows, dtype=jnp.int32),
-                             server.cfg.batch_size)
-        sst, _ = server.host_tx_enqueue(sst, resp, flow_of, fvalid)
-        # server NIC sends responses back over the wire
-        sst, rslots, rvalid2 = server.nic_fetch(sst)
+        with jax.named_scope(tlm.SCOPE_SERVER):
+            # server host writes responses to its TX rings (single
+            # memory write)
+            flow_of = jnp.repeat(
+                jnp.arange(server.cfg.n_flows, dtype=jnp.int32),
+                server.cfg.batch_size)
+            sst, _ = server.host_tx_enqueue(sst, resp, flow_of, fvalid)
+            # server NIC sends responses back over the wire
+            sst, rslots, rvalid2 = server.nic_fetch(sst)
         m = rslots.shape[0] * rslots.shape[1]
         # wire -> client NIC -> completion queues
-        cst, done, dvalid = client.nic_pipeline(cst, rslots.reshape(m, w),
-                                                rvalid2.reshape(m))
+        with jax.named_scope(tlm.SCOPE_CLIENT):
+            cst, done, dvalid = client.nic_pipeline(
+                cst, rslots.reshape(m, w), rvalid2.reshape(m))
+        # what every ring holds at the step's end: the waits of the
+        # RPCs still in flight
+        cst = client.count_queues(cst)
+        sst = server.count_queues(sst)
         return cst, sst, hstate, done, dvalid
 
     return step

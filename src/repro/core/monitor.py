@@ -20,6 +20,11 @@ COUNTERS = (
     "drops_tx_full",      # TX ring rejected a host/loadgen enqueue
     "drops_exchange",     # compacted cross-shard bucket overflowed
     "batches_emitted",
+    # ring occupancy summed over step ends (``DaggerFabric.count_queues``):
+    # each RPC in flight adds one per step it waits in that kind of ring
+    "queued_tx",          # host -> NIC TX rings
+    "queued_fifo",        # flow FIFOs (request buffer references)
+    "queued_rx",          # NIC -> host RX rings
 )
 
 

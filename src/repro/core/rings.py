@@ -23,6 +23,8 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 
+from repro.core import telemetry as tlm
+
 
 def rank_within(mask):
     """mask [..., N] bool -> rank of each True among Trues (along last dim).
@@ -104,6 +106,7 @@ class Ring:
     def occupancy(self):
         return self.tail - self.head
 
+    @jax.named_scope(tlm.SCOPE_RING_PUSH)
     def push(self, queue_ids, slots, valid, use_pallas: bool = False):
         """Push slots [N, W] to queues [N]; returns (ring, accepted [N]).
 
@@ -127,6 +130,7 @@ class Ring:
             accepted.astype(jnp.int32), mode="drop")
         return Ring(buf, self.head, self.tail + n_acc_per_q), accepted
 
+    @jax.named_scope(tlm.SCOPE_RING_PEEK)
     def peek(self, max_n: int):
         """Read up to max_n slots from every queue head.
 
@@ -139,6 +143,7 @@ class Ring:
         valid = offs[None, :] < (self.tail - self.head)[:, None]
         return slots, valid
 
+    @jax.named_scope(tlm.SCOPE_RING_ADVANCE)
     def advance(self, n_per_queue):
         return Ring(self.buf, self.head + n_per_queue, self.tail)
 

@@ -48,6 +48,50 @@ import jax.numpy as jnp
 
 LAT_BINS = 64        # default histogram width (latencies in [0, 62] + ovf)
 
+# Names of the fused step's phases on the device.  Each is a
+# ``jax.named_scope``: compile-time metadata that changes no operation,
+# kept in every compiled instruction's ``op_name`` as a path such as
+# ``jit(run_steps)/while/body/vmap(dagger.client)/dagger.nic.fetch/
+# dagger.ring.peek/gather``, which a profiler trace of the program can be
+# attributed by.  The names are stable: a reader of a trace names them.
+SCOPE_LOADGEN = "dagger.loadgen"            # open-loop injection
+SCOPE_TELEMETRY = "dagger.telemetry"        # observe + tick
+SCOPE_CLIENT = "dagger.client"              # the client NIC's part
+SCOPE_SERVER = "dagger.server"              # the server NIC's part
+SCOPE_HANDLER = "dagger.handler"            # the server's RPC handler
+SCOPE_QUEUES = "dagger.queues"              # queue counters, step's end
+SCOPE_NIC_ENQUEUE = "dagger.nic.enqueue"    # host_tx_enqueue
+SCOPE_NIC_FETCH = "dagger.nic.fetch"        # nic_fetch
+SCOPE_NIC_DELIVER = "dagger.nic.deliver"    # nic_deliver, both routes
+SCOPE_NIC_EMIT = "dagger.nic.emit"          # nic_sched_emit
+SCOPE_NIC_DRAIN = "dagger.nic.drain"        # host_rx_drain
+SCOPE_NIC_PIPELINE = "dagger.nic.pipeline"  # nic_pipeline's megakernel
+SCOPE_RING_PUSH = "dagger.ring.push"
+SCOPE_RING_PEEK = "dagger.ring.peek"
+SCOPE_RING_ADVANCE = "dagger.ring.advance"
+SCOPE_KVS_GET = "dagger.kvs.get"
+SCOPE_KVS_SET = "dagger.kvs.set"
+SCOPE_SWITCH_FETCH = "dagger.switch.fetch"      # phase A + crossbar
+SCOPE_SWITCH_DELIVER = "dagger.switch.deliver"  # phase B
+SCOPE_SWITCH_EMIT = "dagger.switch.emit"        # phase C
+SCOPE_SWITCH_DRAIN = "dagger.switch.drain"      # phase D
+SCOPE_EXCHANGE = "dagger.exchange"              # the ToR all-to-all
+SCOPE_DECODE_MODEL = "dagger.decode.model"      # the LM decode step
+
+# The scopes of a loopback engine's window program (``TenantEngine``
+# with a generator and telemetry); a stateful KVS handler adds
+# ``SCOPE_KVS_GET`` and ``SCOPE_KVS_SET``.
+LOOPBACK_SCOPES = (
+    SCOPE_LOADGEN, SCOPE_TELEMETRY, SCOPE_CLIENT, SCOPE_SERVER,
+    SCOPE_HANDLER, SCOPE_QUEUES, SCOPE_NIC_ENQUEUE, SCOPE_NIC_FETCH,
+    SCOPE_NIC_DELIVER, SCOPE_NIC_EMIT, SCOPE_NIC_DRAIN, SCOPE_RING_PUSH,
+    SCOPE_RING_PEEK, SCOPE_RING_ADVANCE)
+
+# Host span (``jax.profiler.TraceAnnotation``, on the profiler's clock)
+# around the host side of an engine entry: argument handling, ``unalias``
+# and the jitted call, up to the return of the dispatch.
+SPAN_ENGINE_DISPATCH = "dagger.engine.dispatch"
+
 
 @jax.tree_util.register_dataclass
 @dataclass

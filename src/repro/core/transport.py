@@ -47,6 +47,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from repro.core import telemetry as tlm
+
 
 def shard_map(f, *, mesh, in_specs, out_specs):
     """``jax.shard_map`` as every dataplane caller uses it: per-lane
@@ -70,6 +72,7 @@ def shift_tiles(tile, axis: str, n_lanes: int, offset: int = 1):
     return jax.tree.map(lambda x: jax.lax.ppermute(x, axis, perm), tile)
 
 
+@jax.named_scope(tlm.SCOPE_EXCHANGE)
 def all_to_all_tiles(tile, axis: str):
     """All-to-all exchange of per-destination tile buckets along a mesh
     axis.  Per-lane view: leaf shape [n_lanes * bucket, ...] where block
@@ -135,6 +138,7 @@ def bucket_valid(counts, cap: int):
     return lane.reshape(-1)
 
 
+@jax.named_scope(tlm.SCOPE_EXCHANGE)
 def exchange_compact(rows, valid, dest_dev, axis: str, n_dev: int,
                      cap: int):
     """Compacted all-to-all (call INSIDE shard_map): compact the local

@@ -192,30 +192,37 @@ class Switch:
         if fused:
             sts, flat_r, fv, ntel = fused_switch_front(fab, stacked, tel)
         else:
-            # every NIC fetches its host-written tile (CCI-P batched read)
-            sts, slots, valid = jax.vmap(fab.nic_fetch)(stacked)
-            w = slots.shape[-1]
-            flat = slots.reshape(t, -1, w)
-            fval = valid.reshape(t, -1)
-            # read port 1: destination credentials for outgoing RPCs;
-            # responses travel back to the connection's *client* NIC which
-            # is also stored as dest on the serving side's conn entry
-            cid = flat[..., 0]
-            dest, hit = jax.vmap(ConnTable.read_dest)(sts.conn, cid)
+            with jax.named_scope(tlm.SCOPE_SWITCH_FETCH):
+                # every NIC fetches its host-written tile (CCI-P batched
+                # read)
+                sts, slots, valid = jax.vmap(fab.nic_fetch)(stacked)
+                w = slots.shape[-1]
+                flat = slots.reshape(t, -1, w)
+                fval = valid.reshape(t, -1)
+                # read port 1: destination credentials for outgoing RPCs;
+                # responses travel back to the connection's *client* NIC
+                # which is also stored as dest on the serving side's conn
+                # entry
+                cid = flat[..., 0]
+                dest, hit = jax.vmap(ConnTable.read_dest)(sts.conn, cid)
 
-            # the L2 crossbar: all tiers' tiles against all destinations
-            all_slots = flat.reshape(-1, w)
-            all_valid = (fval & hit).reshape(-1)
-            all_dest = dest.reshape(-1)
-            sel = (all_dest[None, :] == jnp.arange(t)[:, None]) \
-                & all_valid[None, :]                       # [T, T*N]
-            sts = jax.vmap(fab.nic_deliver, in_axes=(0, None, 0))(
-                sts, all_slots, sel)
-            sts = jax.vmap(fab.nic_sched_emit)(sts)
+                # the L2 crossbar: all tiers' tiles against all
+                # destinations
+                all_slots = flat.reshape(-1, w)
+                all_valid = (fval & hit).reshape(-1)
+                all_dest = dest.reshape(-1)
+                sel = (all_dest[None, :] == jnp.arange(t)[:, None]) \
+                    & all_valid[None, :]                       # [T, T*N]
+            with jax.named_scope(tlm.SCOPE_SWITCH_DELIVER):
+                sts = jax.vmap(fab.nic_deliver, in_axes=(0, None, 0))(
+                    sts, all_slots, sel)
+            with jax.named_scope(tlm.SCOPE_SWITCH_EMIT):
+                sts = jax.vmap(fab.nic_sched_emit)(sts)
 
             # dispatch: EVERY tier drains its RX rings (completion queues)
-            sts, recs, rvalid = jax.vmap(
-                lambda s: fab.host_rx_drain(s, fab.cfg.batch_size))(sts)
+            with jax.named_scope(tlm.SCOPE_SWITCH_DRAIN):
+                sts, recs, rvalid = jax.vmap(
+                    lambda s: fab.host_rx_drain(s, fab.cfg.batch_size))(sts)
             flat_r = jax.tree.map(
                 lambda x: x.reshape((t, -1) + x.shape[3:]), recs)
             fv = rvalid.reshape(t, -1)
@@ -362,12 +369,13 @@ class Switch:
                 # open-loop injection, device-local, before the fetch
                 sts, lgen = jax.vmap(loadgen.inject)(sts, lgen)
             dev = jax.lax.axis_index(axis)
-            sts, slots, valid = jax.vmap(fab.nic_fetch)(sts)
-            w = slots.shape[-1]
-            flat = slots.reshape(tl, -1, w)
-            fval = valid.reshape(tl, -1)
-            cid = flat[..., 0]
-            dest, hit = jax.vmap(ConnTable.read_dest)(sts.conn, cid)
+            with jax.named_scope(tlm.SCOPE_SWITCH_FETCH):
+                sts, slots, valid = jax.vmap(fab.nic_fetch)(sts)
+                w = slots.shape[-1]
+                flat = slots.reshape(tl, -1, w)
+                fval = valid.reshape(tl, -1)
+                cid = flat[..., 0]
+                dest, hit = jax.vmap(ConnTable.read_dest)(sts.conn, cid)
 
             # ToR hop: one bucket per destination device, exchanged
             # all-to-all — full tile + mask (order-exact oracle) or
@@ -419,15 +427,19 @@ class Switch:
                 gids = dev * tl + jnp.arange(tl, dtype=jnp.int32)
                 sel = (all_dest[None, :] == gids[:, None]) \
                     & all_valid[None, :]
-                sts = jax.vmap(fab.nic_deliver, in_axes=(0, None, 0))(
-                    sts, all_slots, sel)
-                sts = jax.vmap(fab.nic_sched_emit)(sts)
+                with jax.named_scope(tlm.SCOPE_SWITCH_DELIVER):
+                    sts = jax.vmap(fab.nic_deliver, in_axes=(0, None, 0))(
+                        sts, all_slots, sel)
+                with jax.named_scope(tlm.SCOPE_SWITCH_EMIT):
+                    sts = jax.vmap(fab.nic_sched_emit)(sts)
 
                 # dispatch: every local tier drains; handlers are selected
                 # by the tier's GLOBAL id so heterogeneous handler lists
                 # work
-                sts, recs, rvalid = jax.vmap(
-                    lambda s: fab.host_rx_drain(s, fab.cfg.batch_size))(sts)
+                with jax.named_scope(tlm.SCOPE_SWITCH_DRAIN):
+                    sts, recs, rvalid = jax.vmap(
+                        lambda s: fab.host_rx_drain(
+                            s, fab.cfg.batch_size))(sts)
                 flat_r = jax.tree.map(
                     lambda x: x.reshape((tl, -1) + x.shape[3:]), recs)
                 fv = rvalid.reshape(tl, -1)

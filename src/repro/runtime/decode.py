@@ -261,9 +261,10 @@ class DecodeEngine:
             # rows are rewritten before any admitted request attends
             # them, so they are unobservable.
             active = slots.req_id >= 0
-            logits, cache = model.decode_step(params, cache,
-                                              slots.tok[:, None],
-                                              slots.pos)
+            with jax.named_scope(tlm.SCOPE_DECODE_MODEL):
+                logits, cache = model.decode_step(params, cache,
+                                                  slots.tok[:, None],
+                                                  slots.pos)
             nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
             in_prompt = slots.pos < slots.prompt_len - 1

@@ -17,6 +17,7 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 
+from repro.core import telemetry as tlm
 from repro.core.load_balancer import fnv1a_words
 from repro.kernels import kv_probe as kvp
 
@@ -83,6 +84,7 @@ class DeviceKVS:
     def _read(self, arr, bucket, n):
         return arr[self._cells(bucket, n)]
 
+    @jax.named_scope(tlm.SCOPE_KVS_GET)
     def get(self, st: KVSState, key_words, valid=None):
         """key_words: [N, KW] -> (values [N, VW], hit [N])."""
         n = key_words.shape[0]
@@ -103,6 +105,7 @@ class DeviceKVS:
                     n_hit=jnp.sum(hit.astype(jnp.int32)))
         return st2, val, hit
 
+    @jax.named_scope(tlm.SCOPE_KVS_SET)
     def set(self, st: KVSState, key_words, val_words, valid=None):
         """Insert/update [N] records.
 
