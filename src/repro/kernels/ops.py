@@ -12,7 +12,7 @@ import jax
 from repro.kernels.decode_attn import decode_attention as _decode_attention
 from repro.kernels.hash_steer import hash_steer as _hash_steer
 from repro.kernels.hash_steer import hash_steer_static as _hash_steer_static
-from repro.kernels.kv_probe import kv_probe as _kv_probe
+from repro.kernels.kv_probe import probe as _kv_probe
 from repro.kernels.nic_deliver import nic_deliver_fused as _nic_deliver_fused
 from repro.kernels.ring_copy import ring_gather as _ring_gather
 from repro.kernels.ring_push import ring_push as _ring_push

@@ -1,5 +1,6 @@
 """Per-kernel shape/dtype sweeps: Pallas (interpret) vs pure-jnp oracle."""
 import jax
+import jax.extend
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -172,43 +173,165 @@ def test_rpc_pack_matches_serdes_with_fragments():
                                   np.arange(n) + 1000)
 
 
-@pytest.mark.parametrize("nb,ways,vw,n,dup", [(8, 2, 4, 4, False),
-                                              (64, 4, 8, 16, False),
-                                              (16, 8, 2, 33, False),
-                                              (64, 4, 8, 130, True)])
-def test_kv_probe_sweep(nb, ways, vw, n, dup):
-    """Packed-table probe vs the logical-layout oracle.  ``dup`` gives
-    every bucket's ways 0 and 1 the same tag with different keys (two
-    keys whose 32-bit hashes collide): only the key tells them apart."""
+def _probe_store(nb, ways, vw, n, dup, seed=0):
+    """A store's logical tables, its packed tables, and ``n`` queries:
+    half hit, half miss.  ``dup`` gives every bucket's ways 0 and 1 the
+    same tag with different keys (two keys whose 32-bit hashes
+    collide), and aims the hits at way 1: only the key tells them
+    apart."""
     from repro.kernels.kv_probe import pack
     kw = 2
-    tags = jax.random.randint(KEY, (nb, ways), 1, 2**31 - 1,
+    key = lambda i: jax.random.fold_in(  # noqa: E731
+        jax.random.PRNGKey(i), seed) if seed else jax.random.PRNGKey(i)
+    tags = jax.random.randint(key(0), (nb, ways), 1, 2**31 - 1,
                               jnp.int32).astype(jnp.uint32)
     if dup:
         tags = tags.at[:, 1].set(tags[:, 0])
-    keys = jax.random.randint(jax.random.PRNGKey(5), (nb, ways, kw),
-                              -1000, 1000, jnp.int32)
-    vals = jax.random.randint(jax.random.PRNGKey(1), (nb, ways, vw),
-                              0, 1000, jnp.int32)
-    qb = jax.random.randint(jax.random.PRNGKey(2), (n,), 0, nb, jnp.int32)
-    qw = jax.random.randint(jax.random.PRNGKey(3), (n,), 0, ways, jnp.int32)
+    keys = jax.random.randint(key(5), (nb, ways, kw), -1000, 1000, jnp.int32)
+    vals = jax.random.randint(key(1), (nb, ways, vw), 0, 1000, jnp.int32)
+    qb = jax.random.randint(key(2), (n,), 0, nb, jnp.int32)
+    qw = jax.random.randint(key(3), (n,), 0, ways, jnp.int32)
     if dup:
         qw = jnp.ones_like(qw)
-    # half the queries hit, half miss
-    miss = jax.random.randint(jax.random.PRNGKey(4), (n,), 0, 2,
-                              jnp.int32) == 0
+    miss = jax.random.randint(key(4), (n,), 0, 2, jnp.int32) == 0
     qt = jnp.where(miss, jnp.uint32(0xDEADBEEF), tags[qb, qw])
     qk = keys[qb, qw]
-    av, ah = ops.kv_probe(pack(tags), pack(keys.reshape(nb, ways * kw)),
-                          pack(vals.reshape(nb, ways * vw)), qb, qt, qk,
-                          ways=ways, vw=vw)
-    bv, bh = ref.ref_kv_probe(tags, keys, vals, qb, qt, qk)
-    np.testing.assert_array_equal(np.asarray(av), np.asarray(bv))
-    np.testing.assert_array_equal(np.asarray(ah), np.asarray(bh))
-    if dup:
-        assert bool(bh.any())
-        np.testing.assert_array_equal(np.asarray(av)[np.asarray(bh)],
-                                      np.asarray(vals[qb, 1])[np.asarray(bh)])
+    packed = (pack(tags), pack(keys.reshape(nb, ways * kw)),
+              pack(vals.reshape(nb, ways * vw)))
+    return (tags, keys, vals), packed, (qb, qt, qk)
+
+
+# (stores, which operands carry them under ``jax.vmap``): "both"; the
+# queries alone against one store; the stores alone for one query set;
+# "nested", a vmap of a vmap over [2, stores]
+_PROBE_CASES = [(8, 2, 4, 4, False, None), (64, 4, 8, 16, False, None),
+                (16, 8, 2, 33, False, None), (64, 4, 8, 130, True, None),
+                (16, 4, 2, 130, True, (1, "both")),
+                (16, 4, 2, 130, True, (3, "both")),
+                (64, 4, 8, 33, False, (3, "both")),
+                (16, 4, 2, 130, True, (3, "queries")),
+                (16, 4, 2, 130, True, (3, "tables")),
+                (16, 4, 2, 33, True, (3, "nested"))]
+
+
+@pytest.mark.parametrize(
+    "nb,ways,vw,n,dup,vmap", _PROBE_CASES,
+    ids=["-".join(map(str, c[:5])) + ("" if c[5] is None
+                                      else f"-vmap{c[5][0]}{c[5][1]}")
+         for c in _PROBE_CASES])
+def test_kv_probe_sweep(nb, ways, vw, n, dup, vmap):
+    """Packed-table probe vs the logical-layout oracle, one store or
+    ``jax.vmap``-ed over stacked stores (each against its own oracle)."""
+    probe = lambda *a: ops.kv_probe(*a, ways=ways, vw=vw)  # noqa: E731
+
+    def check(got, logical, queries):
+        want = ref.ref_kv_probe(*logical, *queries)
+        np.testing.assert_array_equal(np.asarray(got[0]),
+                                      np.asarray(want[0]))
+        np.testing.assert_array_equal(np.asarray(got[1]),
+                                      np.asarray(want[1]))
+        return want
+
+    if vmap is None:
+        logical, packed, queries = _probe_store(nb, ways, vw, n, dup)
+        bv, bh = check(probe(*packed, *queries), logical, queries)
+        if dup:
+            assert bool(bh.any())
+            np.testing.assert_array_equal(
+                np.asarray(bv)[np.asarray(bh)],
+                np.asarray(logical[2][queries[0], 1])[np.asarray(bh)])
+        return
+    n_stores, axes = vmap
+    stores = [_probe_store(nb, ways, vw, n, dup, seed=s)
+              for s in range(n_stores)]
+    stack = lambda part: [jnp.stack(x) for x in zip(  # noqa: E731
+        *[st[part] for st in stores])]
+    tables, queries = stack(1), stack(2)
+    if axes == "both":
+        got = jax.vmap(probe)(*tables, *queries)
+        pairs = [(s, s) for s in range(n_stores)]
+    elif axes == "queries":
+        got = jax.vmap(probe, in_axes=(None,) * 3 + (0,) * 3)(
+            *stores[0][1], *queries)
+        pairs = [(0, s) for s in range(n_stores)]
+    elif axes == "tables":
+        got = jax.vmap(probe, in_axes=(0,) * 3 + (None,) * 3)(
+            *tables, *stores[0][2])
+        pairs = [(s, 0) for s in range(n_stores)]
+    else:
+        # outer row 1 holds the stores in reverse order
+        flip = lambda xs: [jnp.stack([x, x[::-1]]) for x in xs]  # noqa: E731
+        got = jax.vmap(jax.vmap(probe))(*flip(tables), *flip(queries))
+        got = [g.reshape((-1,) + g.shape[2:]) for g in got]
+        pairs = [(s, s) for s in range(n_stores)] + [
+            (n_stores - 1 - s,) * 2 for s in range(n_stores)]
+    assert all(g.shape[0] == len(pairs) for g in got)
+    for i, (t, q) in enumerate(pairs):
+        check((got[0][i], got[1][i]), stores[t][0], stores[q][2])
+
+
+
+@pytest.mark.parametrize("axes", ["tables_apart", "queries_over_stacks"])
+def test_kv_probe_vmap_refuses(axes):
+    """The probe's batching rule folds a vmapped axis into the stores
+    only where the three tables carry it together, and into the query
+    sets only against one store: tables batched apart, or queries
+    batched against stores stacked by an inner ``jax.vmap``, raise
+    instead of broadcasting a table."""
+    probe = lambda *a: ops.kv_probe(*a, ways=4, vw=2)  # noqa: E731
+    stores = [_probe_store(16, 4, 2, 33, False, seed=s) for s in range(2)]
+    tables, queries = [[jnp.stack(x) for x in zip(*[st[part]
+                                                      for st in stores])]
+                       for part in (1, 2)]
+    with pytest.raises(NotImplementedError):
+        if axes == "tables_apart":
+            jax.vmap(probe, in_axes=(0, None, 0, 0, 0, 0))(
+                tables[0], stores[0][1][1], tables[2], *queries)
+        else:
+            jax.vmap(jax.vmap(probe), in_axes=(None,) * 3 + (0,) * 3)(
+                *tables, *[jnp.stack([q, q]) for q in queries])
+
+def _eqns(jaxpr, loops=()):
+    """Every equation of ``jaxpr`` and of the jaxprs it calls, outside
+    Pallas kernels, with the loop primitives that enclose it."""
+    for e in jaxpr.eqns:
+        yield e, loops
+        if e.primitive.name == "pallas_call":
+            continue
+        inner = loops + ((e.primitive.name,)
+                         if e.primitive.name in ("while", "scan") else ())
+        for p in e.params.values():
+            for sub in (p if isinstance(p, (tuple, list)) else (p,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if isinstance(sub, jax.extend.core.Jaxpr):
+                    yield from _eqns(sub, inner)
+
+
+def test_kv_probe_vmapped_get_is_one_call_over_the_stacked_tables():
+    """``jax.vmap`` of ``DeviceKVS.get`` on the kernel route probes the
+    stacked stores with one kernel call and touches the tables only
+    there: no loop over the stores, no slice or conversion of a table
+    (JAX's own batching of a kernel with a batched scalar-prefetch
+    operand loops over the batch and slices every table per trip)."""
+    from repro.runtime.kvs import DeviceKVS
+    # tables of 256 and 512 rows; 33 queries pad to 128 rows of output
+    kvs = DeviceKVS(n_buckets=8192, ways=4, key_words=2, value_words=2,
+                    use_pallas=True)
+    dbs = kvs.init_state_batch(3)
+    keys = jnp.zeros((3, 33, 2), jnp.int32)
+    jaxpr = jax.make_jaxpr(jax.vmap(kvs.get))(dbs, keys).jaxpr
+    tables = {tuple(x.shape) for x in (dbs.tags, dbs.keys, dbs.vals)}
+    calls = [(e, loops) for e, loops in _eqns(jaxpr)
+             if e.primitive.name == "pallas_call"]
+    assert len(calls) == 1 and calls[0][1] == ()
+    # the kernel reads the stacks themselves
+    assert {tuple(v.aval.shape) for v in calls[0][0].invars} >= tables
+    rows = {t[-2:] for t in tables}
+    for e, _ in _eqns(jaxpr):
+        if e.primitive.name in ("dynamic_slice", "bitcast_convert_type",
+                                "gather", "slice", "copy"):
+            assert not any(tuple(v.aval.shape[-2:]) in rows
+                           for v in e.invars if hasattr(v, "aval")), e
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])

@@ -17,6 +17,7 @@ device owns one NIC slot and the inter-shard paths really cross device
 boundaries.  Tenant counts are multiples of 8 so both shapes divide.
 """
 import jax
+import jax.extend
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -34,6 +35,22 @@ from repro.core.virtualization import Switch
 PALLAS_CASES = [False, pytest.param(True, marks=pytest.mark.requires_pallas)]
 
 N_TENANTS = 8            # divides 1/2/4/8-device meshes
+
+
+def _eqns(jaxpr, loops=()):
+    """Every equation of ``jaxpr`` and of the jaxprs it calls, outside
+    Pallas kernels, with the loop primitives that enclose it."""
+    for e in jaxpr.eqns:
+        yield e, loops
+        if e.primitive.name == "pallas_call":
+            continue
+        inner = loops + ((e.primitive.name,)
+                         if e.primitive.name in ("while", "scan") else ())
+        for p in e.params.values():
+            for sub in (p if isinstance(p, (tuple, list)) else (p,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if isinstance(sub, jax.extend.core.Jaxpr):
+                    yield from _eqns(sub, inner)
 
 
 def assert_trees_equal(a, b, msg=""):
@@ -232,6 +249,60 @@ def test_sharded_kvs_parity(use_pallas):
     db1 = jax.tree.map(lambda x: x[1], sdb)
     _, _, hit = kvs.get(db1, keys)
     assert not bool(hit.any())
+
+
+@pytest.mark.requires_pallas
+def test_sharded_kvs_probe_kernel_parity():
+    """The store's GET probe on the kernel route under the sharded
+    engine (``jax.vmap`` inside ``shard_map``, over each device's shard of
+    the stacked stores) == ``make_tenant_engine``: every key SET is read
+    back by a GET, and the program holds one probe call, inside the step
+    loop alone (no loop over the stores around it)."""
+    from repro.runtime.kvs import DeviceKVS
+    client, server = _fabrics(n_flows=2, batch=4)
+    kvs = DeviceKVS(n_buckets=512, ways=4, key_words=2, value_words=4,
+                    use_pallas=True)
+    pw = client.slot_words - serdes.HEADER_WORDS
+    enq = jax.jit(client.host_tx_enqueue)
+
+    n = 4
+    csts, ssts = [], []
+    for t in range(N_TENANTS):
+        cst, sst = client.init_state(), server.init_state()
+        cst = client.open_connection(cst, 1, 0, 1, LB_ROUND_ROBIN)
+        sst = server.open_connection(sst, 1, 0, 0, LB_ROUND_ROBIN)
+        # each key SET, then read back by a GET on the same flow
+        pay = np.zeros((2 * n, pw), np.int32)
+        pay[:, 0] = np.tile(np.arange(n) + 1 + 10 * t, 2)  # per-tenant keys
+        pay[:n, 2] = np.arange(n) + 100 + 10 * t      # per-tenant values
+        recs = serdes.make_records(
+            np.full(2 * n, 1, np.int32), np.arange(2 * n, dtype=np.int32),
+            np.repeat(np.int32([1, 0]), n),            # fn_id 1 = SET, 0 = GET
+            np.zeros(2 * n, np.int32), jnp.asarray(pay))
+        cst, _ = enq(cst, recs, jnp.arange(2 * n) % 2)
+        csts.append(cst)
+        ssts.append(sst)
+    stc, sts = stack_states(csts), stack_states(ssts)
+    stc2, sts2 = stack_states(csts), stack_states(ssts)
+
+    teng = kvs.make_tenant_engine(client, server)
+    tc, ts, tdb, tdone = teng.run_steps(
+        stc, sts, 4, hstate=kvs.init_state_batch(N_TENANTS))
+
+    seng = kvs.make_sharded_tenant_engine(client, server)
+    sc, ss, sdb = seng.shard_states(stc2, sts2,
+                                    kvs.init_state_batch(N_TENANTS))
+    jaxpr = seng._run_steps.trace(sc, ss, sdb, 4).jaxpr.jaxpr
+    calls = [loops for e, loops in _eqns(jaxpr)
+             if e.primitive.name == "pallas_call"]
+    assert len(calls) == 1 and len(calls[0]) == 1, calls
+    sc, ss, sdb, sdone = seng.run_steps(sc, ss, 4, hstate=sdb)
+    np.testing.assert_array_equal(np.asarray(tdone), np.asarray(sdone))
+    np.testing.assert_array_equal(np.asarray(sdone), 2 * n)
+    np.testing.assert_array_equal(np.asarray(sdb.n_hit), n)
+    assert_trees_equal(tdb, sdb, "KVS stores diverged across the mesh")
+    assert_trees_equal(tc, sc)
+    assert_trees_equal(ts, ss)
 
 
 # ---------------------------------------------------------------------------

@@ -159,12 +159,16 @@ def test_tenant_stateful_handler_parity():
         assert_trees_equal(jax.tree.map(lambda x: x[t], ts), s_ref)
 
 
-def test_tenant_kvs_parity():
+@pytest.mark.parametrize("use_pallas", [False, True])
+def test_tenant_kvs_parity(use_pallas):
     """DeviceKVS.make_tenant_engine == N separate make_engine runs,
-    store state included (the stateful-handler acceptance config)."""
+    store state included (the stateful-handler acceptance config); with
+    ``use_pallas`` the tenants' GETs are one probe of the stacked
+    stores."""
     from repro.runtime.kvs import DeviceKVS
     client, server = _fabrics(n_flows=2, batch=4)
-    kvs = DeviceKVS(n_buckets=64, ways=4, key_words=2, value_words=4)
+    kvs = DeviceKVS(n_buckets=64, ways=4, key_words=2, value_words=4,
+                    use_pallas=use_pallas)
     pw = client.slot_words - serdes.HEADER_WORDS
     enq = jax.jit(client.host_tx_enqueue)
 
@@ -174,14 +178,15 @@ def test_tenant_kvs_parity():
         cst, sst = client.init_state(), server.init_state()
         cst = client.open_connection(cst, 1, 0, 1, LB_ROUND_ROBIN)
         sst = server.open_connection(sst, 1, 0, 0, LB_ROUND_ROBIN)
-        pay = np.zeros((n, pw), np.int32)
-        pay[:, 0] = np.arange(n) + 1 + 10 * t          # per-tenant keys
-        pay[:, 2] = np.arange(n) + 100 + 10 * t        # per-tenant values
+        # each key SET, then read back by a GET on the same flow
+        pay = np.zeros((2 * n, pw), np.int32)
+        pay[:, 0] = np.tile(np.arange(n) + 1 + 10 * t, 2)  # per-tenant keys
+        pay[:n, 2] = np.arange(n) + 100 + 10 * t      # per-tenant values
         recs = serdes.make_records(
-            np.full(n, 1, np.int32), np.arange(n, dtype=np.int32),
-            np.ones(n, np.int32),                      # fn_id 1 = SET
-            np.zeros(n, np.int32), jnp.asarray(pay))
-        cst, _ = enq(cst, recs, jnp.arange(n) % 2)
+            np.full(2 * n, 1, np.int32), np.arange(2 * n, dtype=np.int32),
+            np.repeat(np.int32([1, 0]), n),            # fn_id 1 = SET, 0 = GET
+            np.zeros(2 * n, np.int32), jnp.asarray(pay))
+        cst, _ = enq(cst, recs, jnp.arange(2 * n) % 2)
         csts.append(cst)
         ssts.append(sst)
     stc, sts = stack_states(csts), stack_states(ssts)
@@ -196,8 +201,8 @@ def test_tenant_kvs_parity():
     tc, ts, tdb, tdone = teng.run_steps(
         stc, sts, 4, hstate=kvs.init_state_batch(n_tenants))
     for t, (c_ref, s_ref, db_ref, d_ref) in enumerate(refs):
-        assert int(tdone[t]) == int(d_ref) == n
-        assert int(tdb.n_set[t]) == n
+        assert int(tdone[t]) == int(d_ref) == 2 * n
+        assert int(tdb.n_set[t]) == int(tdb.n_hit[t]) == n
         assert_trees_equal(jax.tree.map(lambda x: x[t], tdb), db_ref,
                            f"KVS store diverged for tenant {t}")
         assert_trees_equal(jax.tree.map(lambda x: x[t], tc), c_ref)

@@ -9,6 +9,7 @@ the TPU compiler library, and the workers of a parallel run must all
 collect the same tests.  The persistent compilation cache is off around
 the compiles — entries written for a described chip cannot be read back.
 """
+import functools
 import os
 
 import jax
@@ -69,14 +70,31 @@ def test_decode_attention_qwen2_widths(one_chip):
 
 
 def test_kv_probe_hbm_table(one_chip):
-    rows = lambda n: _sds(one_chip, (kvp.packed_rows(NB, n),  # noqa: E731
+    rows = lambda n: _sds(one_chip, (1, kvp.packed_rows(NB, n),  # noqa: E731
                                      kvp.LANES))
-    c = _compile(kvp.kv_probe, _sds(one_chip, (kvp.packed_rows(NB, 4),
+    c = _compile(kvp.kv_probe, _sds(one_chip, (1, kvp.packed_rows(NB, 4),
                                                 kvp.LANES), jnp.uint32),
-                 rows(8), rows(32), _sds(one_chip, (N,)),
-                 _sds(one_chip, (N,), jnp.uint32), _sds(one_chip, (N, 2)),
-                 ways=4, vw=8)
+                 rows(8), rows(32), _sds(one_chip, (1, N)),
+                 _sds(one_chip, (1, N), jnp.uint32),
+                 _sds(one_chip, (1, N, 2)), ways=4, vw=8)
     # the table stays in HBM: no relayout copy of it in the program
+    assert c.memory_analysis().temp_size_in_bytes < (1 << 20)
+
+
+def test_kv_probe_stacked_stores(one_chip):
+    """``jax.vmap`` of the one-store probe over 64 stores at the
+    kvs_mica_tiny cell's size (2^20 buckets x 4 ways, 2 + 2 words, 256
+    queries each): one kernel call over the stacked tables, with no
+    copy, slice or conversion of them in the program."""
+    t, nb = 64, 1 << 20
+    rows = lambda n, dt=jnp.int32: _sds(  # noqa: E731
+        one_chip, (t, kvp.packed_rows(nb, n), kvp.LANES), dt)
+    args = (rows(4, jnp.uint32), rows(8), rows(8), _sds(one_chip, (t, N)),
+            _sds(one_chip, (t, N), jnp.uint32), _sds(one_chip, (t, N, 2)))
+    probe = functools.partial(kvp.probe, ways=4, vw=2, interpret=False)
+    c = jax.jit(jax.vmap(probe)).lower(*args).compile()
+    text = c.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
     assert c.memory_analysis().temp_size_in_bytes < (1 << 20)
 
 
